@@ -484,6 +484,8 @@ def run(csv: Csv):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         common.SMOKE = True
     c = Csv()

@@ -79,12 +79,10 @@ def fence(x):
     timed call that ends in async-dispatched device work is charged its
     full cost inside the timed region — not lazily on the next
     materialize. Host-resident numpy results pass through untouched
-    (the call is then a no-op, kept for timing discipline)."""
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except Exception:
-        pass
+    (the call is then a no-op, kept for timing discipline). A device
+    error raises here rather than vanishing from the timed region."""
+    import jax
+    jax.block_until_ready(x)
     return x
 
 

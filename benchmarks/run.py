@@ -11,6 +11,8 @@ import time
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import common
     from benchmarks.common import Csv
     args = [a for a in sys.argv[1:]]

@@ -9,9 +9,12 @@ paper's Spark shuffle).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
@@ -46,32 +49,37 @@ def dpc(x: np.ndarray, *, dc: Optional[float] = None,
     # --- dc from a sampled distance quantile
     if dc is None:
         s = x[rng.choice(n, size=min(1024, n), replace=False)]
-        d2s = np.asarray(ops.pairwise_sq_l2(s, s))
+        d2s = ops.pairwise_sq_l2_host(s, s)
         pos = np.sqrt(d2s[d2s > 1e-12])
         dc = float(np.quantile(pos, 0.02)) if len(pos) else 1.0
         dc = max(dc, 1e-6)
 
-    # --- rho (gaussian kernel density) and delta, blocked over rows
-    rho = np.empty(n, np.float64)
-    for i in range(0, n, block):
-        d2 = np.asarray(ops.pairwise_sq_l2(x[i:i + block], x))
-        rho[i:i + block] = np.exp(-d2 / (dc * dc)).sum(1) - 1.0
+    # --- rho (gaussian kernel density) and delta, blocked over rows, on
+    # the device: only (n,) vectors come back. Row counts are padded to
+    # powers of two (the index build calls this for thousands of node
+    # sizes) and padding columns are masked out of every reduction.
+    m = max(128, ops._ceil_pow2(n))
+    xp = np.zeros((m, x.shape[1]), np.float32)
+    xp[:n] = x
+    xp = jnp.asarray(xp)
+    bs = min(block, m)
+    rho = np.concatenate([
+        np.asarray(_rho_block(xp, i, bs, n, dc * dc))
+        for i in range(0, n, bs)])[:n].astype(np.float64)
 
     order = np.argsort(-rho, kind="stable")  # descending density
+    # delta_i = min distance to any point ahead of i in that order: higher
+    # density, or equal density and a lower index
+    rank = np.empty(m, np.int32)
+    rank[order] = np.arange(n, dtype=np.int32)
+    rank[n:] = m
+    rank = jnp.asarray(rank)
     delta = np.empty(n, np.float64)
     nneigh = np.zeros(n, np.int64)
-    # delta_i = min distance to any higher-density point
-    for i in range(0, n, block):
-        rows = np.arange(i, min(i + block, n))
-        d2 = np.asarray(ops.pairwise_sq_l2(x[rows], x))
-        d = np.sqrt(np.maximum(d2, 0.0))
-        higher = rho[None, :] > rho[rows][:, None]
-        tie = (rho[None, :] == rho[rows][:, None]) & \
-            (np.arange(n)[None, :] < rows[:, None])
-        hmask = higher | tie
-        dm = np.where(hmask, d, np.inf)
-        delta[rows] = dm.min(1)
-        nneigh[rows] = dm.argmin(1)
+    for i in range(0, n, bs):
+        d2min, arg = _delta_block(xp, rank, i, bs, n)
+        delta[i:i + bs] = np.sqrt(np.asarray(d2min))[:n - i]
+        nneigh[i:i + bs] = np.asarray(arg)[:n - i]
     top = order[0]
     delta[top] = max(delta[np.isfinite(delta)].max(initial=1.0), 1.0)
     nneigh[top] = top
@@ -103,3 +111,29 @@ def dpc(x: np.ndarray, *, dc: Optional[float] = None,
             labels[idx] = labels[nneigh[idx]]
     return DPCResult(labels=labels, centers=centers.astype(np.int64),
                      rho=rho, delta=delta)
+
+
+@functools.partial(jax.jit, static_argnames=("bs",))
+def _rho_block(xp, i0, bs: int, n, dc2):
+    """Gaussian-kernel density of rows [i0, i0 + bs) against the n real
+    rows, self excluded (summed without the self term, so sparse points
+    keep their small densities distinct in fp32)."""
+    xb = jax.lax.dynamic_slice_in_dim(xp, i0, bs)
+    d2 = ops.pairwise_sq_l2(xb, xp)                       # (bs, m)
+    col = jnp.arange(xp.shape[0])[None, :]
+    row = i0 + jnp.arange(bs)[:, None]
+    w = jnp.where((col < n) & (col != row), jnp.exp(-d2 / dc2), 0.0)
+    return jnp.sum(w, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("bs",))
+def _delta_block(xp, rank, i0, bs: int, n):
+    """Squared distance from rows [i0, i0 + bs) to their nearest point of
+    lower rank (ahead in the density order), and its index — the first
+    such index among equal distances."""
+    xb = jax.lax.dynamic_slice_in_dim(xp, i0, bs)
+    d2 = ops.pairwise_sq_l2(xb, xp)
+    rb = jax.lax.dynamic_slice_in_dim(rank, i0, bs)[:, None]
+    ahead = (rank[None, :] < rb) & (jnp.arange(xp.shape[0])[None, :] < n)
+    dm = jnp.where(ahead, jnp.maximum(d2, 0.0), jnp.inf)
+    return jnp.min(dm, axis=1), jnp.argmin(dm, axis=1)

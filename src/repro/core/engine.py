@@ -131,9 +131,8 @@ from repro.core import cost as costm
 from repro.core import query as Q
 from repro.core.lake import _next_pow2
 from repro.kernels import ops
-from repro.sharding.partitioning import (shard_put, strided_tile_layout,
-                                         tile_mesh)
-from repro.train.compression import shard_map_compat
+from repro.sharding.partitioning import (manual_shard_map, shard_put,
+                                         strided_tile_layout, tile_mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -522,6 +521,21 @@ def _knn_device_loop(idx, active0, qs_full, d2_full, rows_full, order,
     return bd, br, jnp.stack([r, nbuck, nrows, nresc]), rr
 
 
+_ROUND_BYTES = 256 << 20   # cap on one straggler round's gathered fp32
+#                            (G, W, cap, d) candidate tile
+
+
+def _round_tiles(w: int, g: int, cap: int, d: int) -> int:
+    """Straggler round width ``w`` clamped so the round's candidate
+    gather stays within ``_ROUND_BYTES``, floored to a power of two (the
+    compiled-shape universe stays bounded). QBS seeds can ask for the
+    whole table in one round when queries converge late — held-out
+    queries in high dimension do — and a width-T gather of a 1M-row
+    table does not fit in device memory."""
+    fit = max(1, _ROUND_BYTES // (4 * max(1, g * cap * d)))
+    return max(1, min(w, 1 << (fit.bit_length() - 1)))
+
+
 @jax.jit
 def _knn_prologue_fast(qs, centroid, radius, masks_tiles=None):
     """``_knn_prologue`` with a packed single-key sort (both loops use
@@ -662,7 +676,8 @@ def batched_knn_device_async(geom: LeafGeometry, data_tiles, qs, k: int,
             padded[:na] = act
             idx = jnp.asarray(padded, jnp.int32)
             active0 = jnp.asarray(np.arange(gp) < na)
-            w = max(1, ws if ws else beam)
+            w = _round_tiles(max(1, ws if ws else beam), gp,
+                             geom.cap, qs.shape[1])
             budget = -(-(l - w1) // w)
             bd, br, loop_stats, retire_round = _knn_device_loop(
                 idx, active0, qs, d2, rows, order, lb_sorted,
@@ -1094,7 +1109,7 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
                 jax.lax.psum(nvalid, "shards"),
                 jax.lax.psum(nresc, "shards"))
 
-    start_fn = jax.jit(shard_map_compat(
+    start_fn = jax.jit(manual_shard_map(
         start, mesh,
         in_specs=(P(None, None), P(None, "shards", None), P(None, None,
                                                             None),
@@ -1104,7 +1119,7 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
         out_specs=(P(None, "shards"), P(None, "shards"),
                    P(None, "shards", None), P(None, "shards"),
                    P(None, "shards"), P(None, None), P(None, None),
-                   P(None), P(None), P(None)),
+                   P(None), P(), P()),
         manual_axes=("shards",)))
 
     def loop(idx, active0, qs_f, lbd_f, lbr_f, order_f, lb_f, mt_f,
@@ -1163,7 +1178,7 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
         rr = jnp.where(act_f, r, rr)
         return gbd, gbr, jnp.stack([r, nbuck, nrows, nresc]), rr
 
-    loop_fn = jax.jit(shard_map_compat(
+    loop_fn = jax.jit(manual_shard_map(
         loop, mesh,
         in_specs=(P(None), P(None), P(None, None), P(None, "shards"),
                   P(None, "shards"), P(None, "shards"), P(None, "shards"),
@@ -1208,7 +1223,8 @@ def batched_knn_sharded(st: ShardedTiles, qs, k: int, *,
     qs_j = jnp.asarray(qs_np)
     l = st.t_total
     w1 = max(1, min(w1 if w1 else max(1, -(-max(1, beam // 2) // s)), l))
-    w = max(1, ws if ws else max(1, -(-beam // s)))
+    w = _round_tiles(max(1, ws if ws else max(1, -(-beam // s))), g,
+                     st.cap, qs_np.shape[1])
     budget = max(1, -(-(l - w1) // w)) if l > w1 else 1
     start_fn, loop_fn = _sharded_knn_fns(
         st.mesh, st.t_local, st.td, st.cap, w1, w, budget, k, interpret,
@@ -1301,7 +1317,7 @@ def _sharded_vr_fns(mesh, t_local: int, td: int, cap: int):
         rad = jnp.concatenate([rad_l, drad_g])
         return _vr_leaf_plan(qs, r, cen, rad)
 
-    plan_fn = jax.jit(shard_map_compat(
+    plan_fn = jax.jit(manual_shard_map(
         plan, mesh,
         in_specs=(P(None, None), P(None), P("shards", None), P("shards"),
                   P(None, None), P(None)),
@@ -1314,7 +1330,7 @@ def _sharded_vr_fns(mesh, t_local: int, td: int, cap: int):
         return _vr_union_eval(qs, r2, sel_u[0], member[0], dt, pp,
                               br)[None]
 
-    eval_fn = jax.jit(shard_map_compat(
+    eval_fn = jax.jit(manual_shard_map(
         ueval, mesh,
         in_specs=(P(None, None), P(None), P("shards", None),
                   P("shards", None, None), P("shards", None),
@@ -1391,6 +1407,7 @@ def _vr_union_eval(qs, r2, sel_u, member, data_tiles, tile_pp,
     pp = jnp.take(tile_pp, sel_u, axis=0).reshape(u * cap)
     cross = jax.lax.dot_general(
         qs, pts, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (g, U*cap)
     d2 = jnp.maximum(qq[:, None] + pp[None, :] - 2.0 * cross, 0.0)
     within = valid & (d2 <= r2[:, None])

@@ -93,6 +93,10 @@ class BuildReport:
     build_s: float
     lm_hit_ratio: float       # mean last-mile hit ratio across leaves
     index_bytes: int
+    # wall seconds per build phase, filled by the platform's build
+    # pipeline: transform, lpgf, index (DPC/tree), layout (permutation +
+    # leaf metadata)
+    phase_s: Dict[str, float] = field(default_factory=dict)
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +184,7 @@ def build_index(features: np.ndarray, *, delta: float = 0.951,
                           seed=seed)
                 cent = np.stack([sub[sel][res.labels == l].mean(0)
                                  for l in np.unique(res.labels)])
-                d2 = np.asarray(ops.pairwise_sq_l2(sub, cent))
+                d2 = ops.pairwise_sq_l2_host(sub, cent)
                 labels = d2.argmin(1).astype(np.int32)
             else:
                 labels = dpc(sub, max_clusters=dpc_max_clusters,
@@ -267,8 +271,7 @@ def fold_into_tree(tree: ClusterTree, enhanced: np.ndarray,
     nb, m = len(enhanced), len(delta_enh)
     leaves = tree.leaf_ids
     cen = tree.centroid[leaves].astype(np.float32)
-    d2 = np.asarray(ops.pairwise_sq_l2(
-        np.asarray(delta_enh, np.float32), cen))
+    d2 = ops.pairwise_sq_l2_host(delta_enh, cen)
     assign = d2.argmin(axis=1)                      # leaf position per row
     # widen ancestor balls so C/R pruning stays conservative
     for j in range(m):

@@ -31,15 +31,21 @@ def mean_nn_distance(x, sample: int = 4096, seed: int = 0) -> float:
     return float(np.sqrt(np.maximum(d[:, 1], 0.0)).mean())
 
 
+_TEMP_BYTES = 1 << 30   # cap on one (block, N) fp32 temporary
+
+
 def lpgf_step(x, radius: float, g_mean: float,
-              step: float = 0.5, block: int = 4096) -> np.ndarray:
+              step: float = 0.5) -> np.ndarray:
     """One force-and-move step. x: (N, D) host array -> moved (N, D).
 
     Displacement = step * F / Σw — the weight-normalized (bounded) pull;
     the raw resultant of the paper's Fig-13 force law grows with the
-    neighbor count and diverges if applied directly."""
+    neighbor count and diverges if applied directly. Rows are evaluated
+    in blocks whose (block, N) fp32 temporaries stay within
+    ``_TEMP_BYTES`` each: 4096 rows up to N = 64k, 256 at N = 1M."""
     xj = jnp.asarray(x, jnp.float32)
     n = x.shape[0]
+    block = max(8, min(4096, _TEMP_BYTES // (4 * max(1, n))) // 8 * 8)
     if n <= block:
         f, w = ops.lpgf_force(xj, float(radius), float(g_mean))
         disp = f / jnp.maximum(w, 1.0)[:, None]
@@ -74,16 +80,14 @@ def _tile_disp(tile, allpts, radius, g_mean, c: float = 1.1):
 
 
 def lpgf(x, *, r_mult: float = 7.5, iters: int = 2, step: float = 0.5,
-         g_mean: Optional[float] = None, block: int = 4096,
-         seed: int = 0) -> np.ndarray:
+         g_mean: Optional[float] = None, seed: int = 0) -> np.ndarray:
     """Full LPGF movement: returns the moved copy of x (original kept by the
     caller for traceability; the displacement matrix M = moved - x)."""
     x = np.asarray(x, np.float32)
     out = x.copy()
     for _ in range(iters):
         g = g_mean if g_mean is not None else mean_nn_distance(out, seed=seed)
-        out = lpgf_step(out, radius=r_mult * g, g_mean=g, step=step,
-                        block=block)
+        out = lpgf_step(out, radius=r_mult * g, g_mean=g, step=step)
     return out
 
 

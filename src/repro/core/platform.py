@@ -159,6 +159,7 @@ def _build_state(raw_table: MMOTable, *, seed: int,
     re-layout, leaf metadata. ``prepare()`` installs the result into the
     live platform; ``build_generation()`` keeps it beside the serving
     state until ``swap()``. Mutates nothing it did not create."""
+    t0 = time.perf_counter()
     d, layout = raw_table.concat_features(columns)
     feats = d
     transform = None
@@ -170,12 +171,15 @@ def _build_state(raw_table: MMOTable, *, seed: int,
                 theta if theta is not None else [],
                 delta_scales if delta_scales is not None else [])
         feats = transform.apply(d)
+    t1 = time.perf_counter()
     if use_lpgf:
         feats = lpgf(feats, iters=lpgf_iters, seed=seed)
+    t2 = time.perf_counter()
     tree, perm, report = build_index(
         feats, delta=delta, min_leaf=min_leaf, max_leaf=max_leaf,
         max_depth=max_depth, dpc_max_clusters=dpc_max_clusters,
         dpc_sample=dpc_sample, seed=seed)
+    t3 = time.perf_counter()
     leaves = tree.leaf_ids
     bucket_id = np.zeros(len(perm), np.int32)
     for b, lid in enumerate(leaves):
@@ -184,9 +188,12 @@ def _build_state(raw_table: MMOTable, *, seed: int,
     bucket_starts = np.concatenate(
         [tree.bucket_start[leaves], [len(perm)]]).astype(np.int32)
     table = raw_table.apply_permutation(perm, bucket_id, bucket_starts)
+    meta = build_leaf_meta(tree, table)
+    report.phase_s = dict(transform=t1 - t0, lpgf=t2 - t1, index=t3 - t2,
+                          layout=time.perf_counter() - t3)
     return dict(table=table, tree=tree, report=report, transform=transform,
                 enhanced=feats[perm], enhanced_unpermuted=feats,
-                layout=layout, meta=build_leaf_meta(tree, table))
+                layout=layout, meta=meta)
 
 
 def _copy_tree(tree: ClusterTree) -> ClusterTree:

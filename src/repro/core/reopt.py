@@ -326,7 +326,9 @@ class ReoptController:
     def _warm_generation(self, gen: Generation):
         """Prewarm plans + device state for the incoming generation so
         the swap does not cause a cold-plan / cold-trace latency spike.
-        Best-effort: a warm-up failure never blocks the swap."""
+        A warm-up failure raises: it is the first run of the incoming
+        generation's programs, and a kernel that does not compile there
+        would fail the first batch served after the swap."""
         sess = self.session
         queries = []
         if self._snapshot is not None:
@@ -339,23 +341,19 @@ class ReoptController:
                          sizes=self.config.prewarm_sizes)
         if sess is None or not queries:
             return
-        try:
-            from repro.core.engine import HybridEngine, plannable
-            shards = sess.shards or None
-            key = self.platform._engine_key(
-                sess.interpret, sess.beam, sess.tile, shards,
-                sess.precision)
-            eng = HybridEngine(
-                gen.tree, gen.table, gen.meta, interpret=sess.interpret,
-                beam=sess.beam, tile=sess.tile,
-                device_loop=sess.device_loop, shards=shards,
-                precision=sess.precision)
-            warm = [q for q in queries if plannable(q)][:4]
-            if warm:
-                eng.execute_batch(warm)
-            gen.engines[key] = eng
-        except Exception:     # pragma: no cover - warm-up is optional
-            gen.engines.clear()
+        from repro.core.engine import HybridEngine, plannable
+        shards = sess.shards or None
+        key = self.platform._engine_key(
+            sess.interpret, sess.beam, sess.tile, shards, sess.precision)
+        eng = HybridEngine(
+            gen.tree, gen.table, gen.meta, interpret=sess.interpret,
+            beam=sess.beam, tile=sess.tile,
+            device_loop=sess.device_loop, shards=shards,
+            precision=sess.precision)
+        warm = [q for q in queries if plannable(q)][:4]
+        if warm:
+            eng.execute_batch(warm)
+        gen.engines[key] = eng
 
     def _swap_pending(self) -> str:
         gen = self._gen

@@ -40,18 +40,20 @@ def pairwise_sq_l2(q, p, interpret: bool = False):
     return ref.pairwise_sq_l2(q, p)
 
 
-def pairwise_sq_l2_blocked(q, p, row_block: int = 4096):
-    """Host-driven row blocking for big M (bounds device memory)."""
-    outs = []
-    for i in range(0, q.shape[0], row_block):
-        outs.append(np_asarray(pairwise_sq_l2(q[i:i + row_block], p)))
+def pairwise_sq_l2_host(q, p) -> "np.ndarray":
+    """``pairwise_sq_l2`` of host arrays, back on the host, with both row
+    counts zero-padded to powers of two (at least 8). A recursive
+    caller — the index build clusters thousands of node sizes — then
+    compiles O(log^2 n) programs instead of one per size."""
     import numpy as np
-    return np.concatenate(outs, axis=0)
-
-
-def np_asarray(x):
-    import numpy as np
-    return np.asarray(x)
+    q = np.asarray(q, np.float32)
+    p = np.asarray(p, np.float32)
+    m, n = len(q), len(p)
+    qp = np.zeros((max(8, _ceil_pow2(m)), q.shape[1]), np.float32)
+    pp = np.zeros((max(8, _ceil_pow2(n)), p.shape[1]), np.float32)
+    qp[:m] = q
+    pp[:n] = p
+    return np.asarray(pairwise_sq_l2(qp, pp))[:m, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -181,6 +183,7 @@ def topk_l2_masked_mp(q, sel, valid, data_tiles, pdata, pscale, pppq, peps,
         pts = data_tiles[tile, slot]                 # (G, R, D) fp32
         pp = jnp.sum(pts * pts, axis=2)
         cross = jnp.einsum("gd,grd->gr", qf, pts,
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
         d2 = jnp.maximum(qq + pp - 2.0 * cross, 0.0)
         d2 = jnp.where(pv, d2, jnp.inf)

@@ -25,6 +25,7 @@ def _kernel(q_ref, p_ref, out_ref):
     pp = jnp.sum(p * p, axis=1, keepdims=True)  # (BN, 1)
     cross = jax.lax.dot_general(
         q, p, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)      # (BM, BN)
     out_ref[...] = jnp.maximum(qq + pp.T - 2.0 * cross, 0.0)
 

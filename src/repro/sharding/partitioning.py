@@ -213,6 +213,16 @@ def strided_tile_layout(n_tiles: int, shards: int
     return perm, t_local, t_pad
 
 
+def manual_shard_map(f, mesh: Mesh, in_specs, out_specs, manual_axes):
+    """``jax.shard_map`` of ``f``, manual over ``manual_axes`` only (other
+    mesh axes stay automatic), with the replication check off: bodies
+    that ``psum``/``all_gather`` make their replicated outputs explicit.
+    A rank-0 output takes the spec ``P()``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=set(manual_axes))
+
+
 def shard_put(x, mesh: Mesh, spec: P):
     """Upload a host array already laid out for ``spec`` — each device
     receives only its slice (no full-array broadcast)."""

@@ -27,20 +27,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes):
-    """``jax.shard_map`` appeared in newer jax releases; older ones only ship
-    ``jax.experimental.shard_map`` with (check_rep, auto) instead of
-    (check_vma, axis_names). Dispatch on what's available so the compressed
-    step lowers on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names=set(manual_axes))
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
+from repro.sharding.partitioning import manual_shard_map
 
 
 def quantize_grad(g, axis: int = -1):
@@ -141,7 +128,7 @@ def make_compressed_train_step(model, tc, mesh, state_dtype="float32"):
             lambda x: P(*("pod",) + (None,) * (x.ndim - 1)), batch_tree)
 
     def wrap(params, opt, err, batch):
-        fn = shard_map_compat(
+        fn = manual_shard_map(
             step, mesh,
             in_specs=(P(), P(), P(), batch_specs(batch)),
             out_specs=(P(), P(), P(), P()),

@@ -371,6 +371,91 @@ def test_union_partially_filled_last_delta_tile():
         assert set(got.tolist()) == set(p.oracle(q).tolist()), dl
 
 
+# ---------------------------------------------------------------------------
+# The running top-k merge (k passes of lane-wise min extraction) against
+# the reference's single stable top_k. Integer-valued points keep every
+# distance exact, so ties are real and both sides must agree bit for bit:
+# same distances, same indices, lower index first among equal distances.
+# ---------------------------------------------------------------------------
+def _int_case(g, c, d, lo=-3, hi=4, dup=1):
+    q = RNG.integers(lo, hi, size=(g, d)).astype(np.float32)
+    p = RNG.integers(lo, hi, size=(g, c // dup, d)).astype(np.float32)
+    p = np.concatenate([p] * dup + [p[:, :c - dup * (c // dup)]], axis=1)
+    return jnp.asarray(q), jnp.asarray(p)
+
+
+def _assert_merge_exact(got, want):
+    gd, gi = map(np.asarray, got)
+    wd, wi = map(np.asarray, want)
+    np.testing.assert_array_equal(gd, wd)
+    np.testing.assert_array_equal(gi, wi)
+
+
+@pytest.mark.parametrize("k,bc", [(5, 16), (10, 32), (33, 16)])
+def test_merge_ties_match_ref(k, bc):
+    """Heavy ties (a 4-value grid, every point duplicated): the merge's
+    buffer-first, lower-position-first law equals the reference's stable
+    top_k over the whole candidate set."""
+    q, p = _int_case(6, 96, 3, lo=0, hi=2, dup=2)
+    v = jnp.asarray(RNG.random((6, 96)) < 0.8)
+    _assert_merge_exact(
+        topk_l2_masked_pallas(q, p, v, k, bg=2, bc=bc, interpret=True),
+        ref.topk_l2_masked(q, p, v, k))
+
+
+@pytest.mark.parametrize("survivors", [0, 1, 7])
+def test_merge_k_exceeds_survivors_across_blocks(survivors):
+    """Fewer valid candidates than k, spread over several blocks: exactly
+    the survivors come back in order, then (inf, -1) slots."""
+    g, c, d, k = 4, 80, 5, 12
+    q, p = _int_case(g, c, d)
+    v = np.zeros((g, c), bool)
+    for i in range(g):
+        v[i, RNG.choice(c, survivors, replace=False)] = True
+    _assert_merge_exact(
+        topk_l2_masked_pallas(q, p, jnp.asarray(v), k, bg=2, bc=16,
+                              interpret=True),
+        ref.topk_l2_masked(q, p, jnp.asarray(v), k))
+
+
+@pytest.mark.parametrize("with_lb", [False, True])
+def test_merge_all_masked_blocks_between_live_ones(with_lb):
+    """Whole blocks masked out between live ones (and a fully masked
+    query): skipped blocks leave the running buffer untouched."""
+    g, c, d, k, bc = 4, 128, 4, 6, 16
+    q, p = _int_case(g, c, d)
+    v = np.ones((g, c), bool)
+    for blk in (1, 2, 5, 6):
+        v[:, blk * bc:(blk + 1) * bc] = False
+    v[3] = False
+    v = jnp.asarray(v)
+    lb2 = jnp.zeros((g, c), jnp.float32) if with_lb else None
+    _assert_merge_exact(
+        topk_l2_masked_pallas(q, p, v, k, bg=2, bc=bc, interpret=True,
+                              lb2=lb2),
+        ref.topk_l2_masked(q, p, v, k))
+
+
+@pytest.mark.parametrize("c,bc", [(300, 64), (1024, 128)])
+def test_merge_k100(c, bc):
+    """k = 100 over several blocks (the widest V.K the engine serves)."""
+    q, p = _int_case(8, c, 6)
+    v = jnp.asarray(RNG.random((8, c)) < 0.7)
+    _assert_merge_exact(
+        topk_l2_masked_pallas(q, p, v, 100, bg=4, bc=bc, interpret=True),
+        ref.topk_l2_masked(q, p, v, 100))
+
+
+@pytest.mark.parametrize("k", [2, 10, 100])
+def test_shared_merge_matches_ref(k):
+    """The shared-point-set kernel: same merge, padding rows excluded."""
+    q = jnp.asarray(RNG.integers(-2, 3, size=(20, 4)).astype(np.float32))
+    p = jnp.asarray(RNG.integers(-2, 3, size=(300, 4)).astype(np.float32))
+    _assert_merge_exact(topk_l2_pallas(q, p, k, bm=16, bn=64,
+                                       interpret=True),
+                        ref.topk_l2(q, p, k))
+
+
 @pytest.mark.parametrize("n,d", [(90, 11), (200, 5), (64, 33), (33, 2)])
 @pytest.mark.parametrize("r,g", [(2.5, 0.7), (10.0, 1.5)])
 def test_lpgf_sweep(n, d, r, g):
