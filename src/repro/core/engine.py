@@ -133,6 +133,7 @@ from repro.core.lake import _next_pow2
 from repro.kernels import ops
 from repro.sharding.partitioning import (manual_shard_map, shard_put,
                                          strided_tile_layout, tile_mesh)
+from repro.utils.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -296,6 +297,17 @@ def _knn_round(act, qs, order, masks_tiles, data_tiles, bucket_rows,
     return d2, rows, jnp.sum(valid, axis=1), resc
 
 
+def _device_masks(masks, g: int):
+    """(g, n) bool device masks from ``masks``, whose rows may stop
+    short of ``g``: the missing tail (a group's unmasked jobs) is all
+    true, built on device instead of being staged and uploaded."""
+    m = jnp.asarray(masks)
+    if m.shape[0] < g:
+        m = jnp.concatenate([m, jnp.ones((g - m.shape[0], m.shape[1]),
+                                         bool)])
+    return m
+
+
 @jax.jit
 def _tile_masks(masks, bucket_rows):
     """Re-layout per-row masks (G, n) into tile-major (G, T, cap) once per
@@ -332,7 +344,8 @@ def batched_knn(geom: LeafGeometry, data_tiles, qs, k: int, *,
     """Exact batched (optionally row-masked) KNN.
 
     qs: (G, d); data_tiles: (T, cap, d) tile-major device copy of the
-    column (see ``tile_data``); masks: optional (G, n) bool device.
+    column (see ``tile_data``); masks: optional (G', n) bool, G' <= G,
+    host or device: rows past G' are all true (built on device).
     Returns (dists (G, k) fp32 L2, rows (G, k) int; -1/inf pad slots).
 
     Exactness: leaves are ranked per query by the lower bound
@@ -351,10 +364,10 @@ def batched_knn(geom: LeafGeometry, data_tiles, qs, k: int, *,
     """
     t0 = time.time()
     qs = jnp.asarray(qs, jnp.float32)
+    g = int(qs.shape[0])
     masks_tiles = None
     if masks is not None:
-        masks_tiles = _tile_masks(jnp.asarray(masks), geom.bucket_rows)
-    g = int(qs.shape[0])
+        masks_tiles = _tile_masks(_device_masks(masks, g), geom.bucket_rows)
     l = geom.n_leaves
     # same packed int32 single-key bound sort as the device path (several
     # times faster than XLA's variadic argsort on CPU); the truncated
@@ -640,59 +653,69 @@ def batched_knn_device_async(geom: LeafGeometry, data_tiles, qs, k: int,
     are started asynchronously here, so when another chunk's host work
     runs in between (the serving pipeline's overlap window), the
     eventual fence usually costs nothing. Results and stats are
-    identical to the synchronous wrapper."""
-    t0 = time.time()
-    qs = jnp.asarray(qs, jnp.float32)
-    masks_tiles = None
-    if masks is not None:
-        masks_tiles = _tile_masks(jnp.asarray(masks), geom.bucket_rows)
-    g = int(qs.shape[0])
-    l = geom.n_leaves
-    w1 = max(1, min(w1 if w1 else max(1, beam // 2), l))
-    order, lb_sorted, d2, rows, active, nvalid, resc = _knn_start(
-        qs, masks_tiles, geom.centroid, geom.radius, data_tiles,
-        geom.bucket_rows, planes, w1=w1, k=k, precision=precision,
-        interpret=interpret)
-    for a in (active, d2, rows, nvalid, resc):
-        _start_d2h(a)
-    t_disp = time.time() - t0
+    identical to the synchronous wrapper. ``qs`` and ``masks`` (whose
+    rows may stop short of ``qs``'s, as in ``batched_knn``) may be host
+    arrays: their upload, and the all-true tail, are part of the
+    ``engine.knn_dispatch`` span; ``finish()`` runs ``engine.knn_fence`` (the first round's
+    results to the host), ``engine.stragglers`` (compaction and the
+    straggler loop's enqueue) and ``engine.straggler_fence``."""
+    with span("engine.knn_dispatch") as disp:
+        qs = jnp.asarray(qs, jnp.float32)
+        g = int(qs.shape[0])
+        masks_tiles = None
+        if masks is not None:
+            masks_tiles = _tile_masks(_device_masks(masks, g),
+                                      geom.bucket_rows)
+        l = geom.n_leaves
+        w1 = max(1, min(w1 if w1 else max(1, beam // 2), l))
+        order, lb_sorted, d2, rows, active, nvalid, resc = _knn_start(
+            qs, masks_tiles, geom.centroid, geom.radius, data_tiles,
+            geom.bucket_rows, planes, w1=w1, k=k, precision=precision,
+            interpret=interpret)
+        for a in (active, d2, rows, nvalid, resc):
+            _start_d2h(a)
 
     def _finish() -> Tuple[np.ndarray, np.ndarray]:
-        t1 = time.time()
-        d2f, rowsf = d2, rows
+        with span("engine.knn_fence") as fence:
+            act = np.nonzero(np.asarray(active))[0]
+            d2f = np.asarray(d2, dtype=np.float32)
+            rowsf = np.asarray(rows)
+            nv, nr = int(nvalid), int(resc)
+        host_s = disp.seconds + fence.seconds
         if stats is not None:
             stats.knn_rounds += 1
             stats.knn_buckets += g * w1
-            stats.rows_scanned += int(nvalid)
+            stats.rows_scanned += nv
             if precision != "fp32":
-                stats.mp_scanned += int(nvalid)
-                stats.mp_rescued += int(resc)
+                stats.mp_scanned += nv
+                stats.mp_rescued += nr
         conv = np.full(g, w1, np.int64)
-        act = np.nonzero(np.asarray(active))[0]
         if len(act) and w1 < l:
-            na = len(act)
-            gp = _next_pow2(na)
-            padded = np.zeros(gp, np.int64)
-            padded[:na] = act
-            idx = jnp.asarray(padded, jnp.int32)
-            active0 = jnp.asarray(np.arange(gp) < na)
-            w = _round_tiles(max(1, ws if ws else beam), gp,
-                             geom.cap, qs.shape[1])
-            budget = -(-(l - w1) // w)
-            bd, br, loop_stats, retire_round = _knn_device_loop(
-                idx, active0, qs, d2, rows, order, lb_sorted,
-                masks_tiles, data_tiles, geom.bucket_rows, planes,
-                w1=w1, w=w, budget=budget, k=k, precision=precision,
-                interpret=interpret)
-            d2f = np.asarray(d2, dtype=np.float32).copy()
-            rowsf = np.asarray(rows).copy()
-            d2f[act] = np.asarray(bd)[:na]
-            rowsf[act] = np.asarray(br)[:na]
-            conv[act] = np.minimum(
-                w1 + np.asarray(retire_round)[:na].astype(np.int64) * w,
-                l)
-            if stats is not None:
+            with span("engine.stragglers") as strag:
+                na = len(act)
+                gp = _next_pow2(na)
+                padded = np.zeros(gp, np.int64)
+                padded[:na] = act
+                idx = jnp.asarray(padded, jnp.int32)
+                active0 = jnp.asarray(np.arange(gp) < na)
+                w = _round_tiles(max(1, ws if ws else beam), gp,
+                                 geom.cap, qs.shape[1])
+                budget = -(-(l - w1) // w)
+                bd, br, loop_stats, retire_round = _knn_device_loop(
+                    idx, active0, qs, d2, rows, order, lb_sorted,
+                    masks_tiles, data_tiles, geom.bucket_rows, planes,
+                    w1=w1, w=w, budget=budget, k=k, precision=precision,
+                    interpret=interpret)
+            with span("engine.straggler_fence") as sfence:
+                bd, br = np.asarray(bd)[:na], np.asarray(br)[:na]
+                retired = np.asarray(retire_round)[:na]
                 rounds, nbuck, nrows, nresc = np.asarray(loop_stats)
+            host_s += strag.seconds + sfence.seconds
+            d2f, rowsf = d2f.copy(), rowsf.copy()
+            d2f[act] = bd
+            rowsf[act] = br
+            conv[act] = np.minimum(w1 + retired.astype(np.int64) * w, l)
+            if stats is not None:
                 stats.knn_rounds += int(rounds)
                 stats.knn_buckets += int(nbuck)
                 stats.rows_scanned += int(nrows)
@@ -700,10 +723,10 @@ def batched_knn_device_async(geom: LeafGeometry, data_tiles, qs, k: int,
                     stats.mp_scanned += int(nrows)
                     stats.mp_rescued += int(nresc)
         if stats is not None:
-            stats.time_s += t_disp + (time.time() - t1)
+            stats.time_s += host_s
         if conv_out is not None:
             conv_out.append(conv)
-        return np.sqrt(np.asarray(d2f)), np.asarray(rowsf).astype(np.int64)
+        return np.sqrt(d2f), rowsf.astype(np.int64)
 
     return _PendingDeviceKnn(_finish)
 
@@ -1951,33 +1974,38 @@ class HybridEngine:
 
         masks: Dict[Q.Query, np.ndarray] = {}
         deferred = []   # numeric groups: (grp, device mask, device count)
-        for (tname, attr), grp in groups.items():
-            if tname == "NE":
-                m, touched = _ne_group_masks(
-                    self.num[attr], self.num_lo[attr], self.num_hi[attr],
-                    self.row_leaf,
-                    jnp.asarray([b.value for b in grp], jnp.float32),
-                    jnp.asarray([b.tol for b in grp], jnp.float32))
-                _start_d2h(m)
-                deferred.append((grp, m, touched))
-            elif tname == "NR":
-                m, touched = _nr_group_masks(
-                    self.num[attr], self.num_lo[attr], self.num_hi[attr],
-                    self.row_leaf,
-                    jnp.asarray([b.lo for b in grp], jnp.float32),
-                    jnp.asarray([b.hi for b in grp], jnp.float32))
-                _start_d2h(m)
-                deferred.append((grp, m, touched))
-            else:  # VR
-                m, touched = self._vr_masks(attr, grp, stats, tile_route)
+        if not groups:
+            return masks
+        with span("engine.masks"):
+            for (tname, attr), grp in groups.items():
+                if tname == "NE":
+                    m, touched = _ne_group_masks(
+                        self.num[attr], self.num_lo[attr],
+                        self.num_hi[attr], self.row_leaf,
+                        jnp.asarray([b.value for b in grp], jnp.float32),
+                        jnp.asarray([b.tol for b in grp], jnp.float32))
+                    _start_d2h(m)
+                    deferred.append((grp, m, touched))
+                elif tname == "NR":
+                    m, touched = _nr_group_masks(
+                        self.num[attr], self.num_lo[attr],
+                        self.num_hi[attr], self.row_leaf,
+                        jnp.asarray([b.lo for b in grp], jnp.float32),
+                        jnp.asarray([b.hi for b in grp], jnp.float32))
+                    _start_d2h(m)
+                    deferred.append((grp, m, touched))
+                else:  # VR
+                    m, touched = self._vr_masks(attr, grp, stats,
+                                                tile_route)
+                    stats.predicate_buckets += int(touched)
+                    for i, b in enumerate(grp):
+                        masks[b] = m[i]
+        with span("engine.masks_fence"):    # stage-boundary fence
+            for grp, m, touched in deferred:
+                m = np.asarray(m)
                 stats.predicate_buckets += int(touched)
                 for i, b in enumerate(grp):
                     masks[b] = m[i]
-        for grp, m, touched in deferred:   # stage-boundary fence
-            m = np.asarray(m)
-            stats.predicate_buckets += int(touched)
-            for i, b in enumerate(grp):
-                masks[b] = m[i]
         return masks
 
     def _vr_plan_sharded(self, attr: str, qs, r):
@@ -2300,17 +2328,12 @@ class HybridEngine:
                 feat_shards, feat_tiles = s, st.t_total
                 feat_cap, feat_dim = st.cap, qs_np.shape[1]
             else:
-                qs = jnp.asarray(np.stack([jobs[i][0].vec()
-                                           for i in idxs]))
-                masks = None
-                if n_masked:
-                    masks = jnp.asarray(np.stack(
-                        [jobs[i][1] for i in idxs[:n_masked]]))
-                    if n_masked < len(idxs):
-                        masks = jnp.concatenate(
-                            [masks,
-                             jnp.ones((len(idxs) - n_masked, self.n),
-                                      bool)])
+                # host arrays, the masked jobs' rows only: the device
+                # loop uploads them and builds the unmasked tail's
+                # all-true rows inside its engine.knn_dispatch span
+                qs = np.stack([jobs[i][0].vec() for i in idxs])
+                masks = np.stack([jobs[i][1] for i in idxs[:n_masked]]) \
+                    if n_masked else None
                 geom = self.geom_dev[attr] if device_loop \
                     else self.geom[attr]
                 tiles = self.vec_tiles_dev[attr] if device_loop \
@@ -2399,16 +2422,17 @@ class HybridEngine:
         QBS beam seeds: plannability checks and grouping are skipped, and
         the job layout is cross-checked against this batch's walk."""
         device_loop = self._resolve_loop(device_loop, plan)
-        t0 = time.time()
-        stats = EngineStats(queries=len(queries),
-                            shards=(self.shards or 0) if device_loop
-                            else 0)
-        pred_masks = self._stage_batch(queries, stats, device_loop, plan)
-        jobs, groups, seeds = self._plan_jobs(queries, pred_masks, plan)
-        job_rows = self._run_jobs(jobs, stats, device_loop,
-                                  groups=groups, seeds=seeds)
-        out = self._finish_walk(queries, pred_masks, jobs, job_rows)
-        stats.time_s = time.time() - t0
+        with span("engine.execute") as sp:
+            stats = EngineStats(queries=len(queries),
+                                shards=(self.shards or 0) if device_loop
+                                else 0)
+            pred_masks = self._stage_batch(queries, stats, device_loop,
+                                           plan)
+            jobs, groups, seeds = self._plan_jobs(queries, pred_masks, plan)
+            job_rows = self._run_jobs(jobs, stats, device_loop,
+                                      groups=groups, seeds=seeds)
+            out = self._finish_walk(queries, pred_masks, jobs, job_rows)
+        stats.time_s = sp.seconds
         return out, stats
 
     def execute_batch_async(self, queries: Sequence[Q.Query], *,
@@ -2435,25 +2459,26 @@ class HybridEngine:
         feedback (convergence widths) is still recorded at
         materialize time."""
         device_loop = self._resolve_loop(device_loop, plan)
-        t0 = time.time()
-        stats = EngineStats(queries=len(queries),
-                            shards=(self.shards or 0) if device_loop
-                            else 0)
-        pred_masks = self._stage_batch(queries, stats, device_loop, plan)
-        jobs, groups, seeds = self._plan_jobs(queries, pred_masks, plan)
-        pending = self._dispatch_jobs(jobs, stats, device_loop,
-                                      groups=groups, seeds=seeds,
-                                      eager=False,
-                                      record_cost=record_cost)
-        t_disp = time.time() - t0
+        with span("engine.execute") as disp:
+            stats = EngineStats(queries=len(queries),
+                                shards=(self.shards or 0) if device_loop
+                                else 0)
+            pred_masks = self._stage_batch(queries, stats, device_loop,
+                                           plan)
+            jobs, groups, seeds = self._plan_jobs(queries, pred_masks, plan)
+            pending = self._dispatch_jobs(jobs, stats, device_loop,
+                                          groups=groups, seeds=seeds,
+                                          eager=False,
+                                          record_cost=record_cost)
 
         def _materialize():
-            t1 = time.time()
-            job_rows = pending.finish()
-            out = self._finish_walk(queries, pred_masks, jobs, job_rows)
+            with span("engine.execute") as epi:
+                job_rows = pending.finish()
+                out = self._finish_walk(queries, pred_masks, jobs,
+                                        job_rows)
             # host-side work only: dispatch + epilogue (the overlap
             # window between the halves is deliberately not counted)
-            stats.time_s = t_disp + (time.time() - t1)
+            stats.time_s = disp.seconds + epi.seconds
             return out, stats
 
         return PendingBatch(_materialize)
@@ -2524,12 +2549,13 @@ class HybridEngine:
         walk (host numpy) — the shared back half of both entry points."""
         out: List[np.ndarray] = []
         ctr = [0]
-        for q in queries:
-            if isinstance(q, Q.VK):
-                ctr[0] += 1  # consume this query's own job slot
-                rows = np.asarray(job_rows[ctr[0] - 1])
-                out.append(rows[rows >= 0].astype(np.int64))
-                continue
-            m = self._walk(q, None, pred_masks, jobs, job_rows, ctr)
-            out.append(np.nonzero(m)[0].astype(np.int64))
+        with span("engine.walk"):
+            for q in queries:
+                if isinstance(q, Q.VK):
+                    ctr[0] += 1  # consume this query's own job slot
+                    rows = np.asarray(job_rows[ctr[0] - 1])
+                    out.append(rows[rows >= 0].astype(np.int64))
+                    continue
+                m = self._walk(q, None, pred_masks, jobs, job_rows, ctr)
+                out.append(np.nonzero(m)[0].astype(np.int64))
         return out

@@ -91,7 +91,6 @@ are thin wrappers over a ``Session`` and return identical results.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -101,6 +100,7 @@ from repro.core import cost as costm
 from repro.core import query as Q
 from repro.core.engine import (EnginePlan, EngineStats, KnnGroupSpec,
                                group_job_specs, plannable)
+from repro.utils.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -312,47 +312,54 @@ class ExecutablePlan:
     def execute(self) -> Tuple[List[np.ndarray], EngineStats]:
         lp = self.logical
         p = self.session.platform
-        t0 = time.time()
-        results: List[Optional[np.ndarray]] = [None] * len(self.norm)
-        if lp.engine_idx:
-            eng_plan = EnginePlan(
-                device_loop=lp.device_loop, job_specs=lp.job_specs,
-                groups=lp.groups, seeds=self._seeds(),
-                shards=lp.shards, precision=self.session.precision)
-            eng = self.session.engine(lp.shards)
-            rows, stats = eng.execute_batch(
-                [self.norm[i] for i in lp.engine_idx], plan=eng_plan)
-            for i, r in zip(lp.engine_idx, rows):
-                results[i] = r
+        with span("plan.execute") as sp:
+            results: List[Optional[np.ndarray]] = [None] * len(self.norm)
+            if lp.engine_idx:
+                eng_plan = EnginePlan(
+                    device_loop=lp.device_loop, job_specs=lp.job_specs,
+                    groups=lp.groups, seeds=self._seeds(),
+                    shards=lp.shards, precision=self.session.precision)
+                eng = self.session.engine(lp.shards)
+                rows, stats = eng.execute_batch(
+                    [self.norm[i] for i in lp.engine_idx], plan=eng_plan)
+                for i, r in zip(lp.engine_idx, rows):
+                    results[i] = r
+                self.session.mp_scanned += stats.mp_scanned
+                self.session.mp_rescued += stats.mp_rescued
+            else:
+                stats = EngineStats()
+            stats.queries = len(self.norm)  # incl. scalar fallbacks (their
+            for i in lp.scalar_idx:         # work is not in engine counters)
+                results[i] = p.execute(self.norm[i], record=False)[0]
+        stats.time_s = sp.seconds
+        self._feedback(stats, cost=True)
+        return results, stats  # type: ignore[return-value]
+
+    def _feedback(self, stats: EngineStats, *, cost: bool) -> None:
+        """The batch's QBS writes, in one ``plan.feedback`` span: each
+        KNN group's converged width (query-aware beam seeding); with
+        ``cost``, the observed per-stage times into the QBS cost rings
+        and the cost model's online-recalibration chance (the
+        predicted-vs-observed loop, module doc); and one representative
+        AST per signature (with the batch's count) into the workload
+        ring that the online re-optimization controller replays against
+        candidate transforms."""
+        p = self.session.platform
+        with span("plan.feedback"):
             for arch, width in stats.knn_group_widths:
                 p.qbs.record_convergence(arch, width)
-            # observed per-stage times into the QBS cost rings, then
-            # give the cost model its online-recalibration chance —
-            # the predicted-vs-observed feedback loop (module doc)
-            for kind, feats, secs in stats.stage_samples:
-                p.qbs.record_cost(kind, feats, secs)
-            cm = getattr(p, "cost_model", None)
-            if cm is not None and stats.stage_samples:
-                cm.maybe_refit(p.qbs)
-            self.session.mp_scanned += stats.mp_scanned
-            self.session.mp_rescued += stats.mp_rescued
-        else:
-            stats = EngineStats()
-        stats.queries = len(self.norm)  # incl. scalar fallbacks (their
-        for i in lp.scalar_idx:         # work is not in engine counters)
-            results[i] = p.execute(self.norm[i], record=False)[0]
-        stats.time_s = time.time() - t0
-        # tuner feedback: one representative AST per signature per batch
-        # (with the batch's count) into the QBS workload ring — what the
-        # online re-optimization controller replays against candidate
-        # transforms
-        reps: Dict[str, list] = {}
-        for q, frag in zip(self.norm, lp.fragments):
-            slot = reps.setdefault(frag.signature, [q, 0])
-            slot[1] += 1
-        for sig, (q, cnt) in reps.items():
-            p.qbs.record_workload(sig, q, cnt)
-        return results, stats  # type: ignore[return-value]
+            if cost:
+                for kind, feats, secs in stats.stage_samples:
+                    p.qbs.record_cost(kind, feats, secs)
+                cm = getattr(p, "cost_model", None)
+                if cm is not None and stats.stage_samples:
+                    cm.maybe_refit(p.qbs)
+            reps: Dict[str, list] = {}
+            for q, frag in zip(self.norm, self.logical.fragments):
+                slot = reps.setdefault(frag.signature, [q, 0])
+                slot[1] += 1
+            for sig, (q, cnt) in reps.items():
+                p.qbs.record_workload(sig, q, cnt)
 
     # ------------------------------------------------------- execute_async
     def execute_async(self, *, record: bool = True) -> "PendingExecution":
@@ -382,43 +389,36 @@ class ExecutablePlan:
         executions never pollute the query-aware feedback loops."""
         lp = self.logical
         p = self.session.platform
-        t0 = time.time()
         pending = None
-        if lp.engine_idx:
-            eng_plan = EnginePlan(
-                device_loop=lp.device_loop, job_specs=lp.job_specs,
-                groups=lp.groups, seeds=self._seeds(),
-                shards=lp.shards, precision=self.session.precision)
-            eng = self.session.engine(lp.shards)
-            pending = eng.execute_batch_async(
-                [self.norm[i] for i in lp.engine_idx], plan=eng_plan)
-        t_disp = time.time() - t0
+        with span("plan.execute") as disp:
+            if lp.engine_idx:
+                eng_plan = EnginePlan(
+                    device_loop=lp.device_loop, job_specs=lp.job_specs,
+                    groups=lp.groups, seeds=self._seeds(),
+                    shards=lp.shards, precision=self.session.precision)
+                eng = self.session.engine(lp.shards)
+                pending = eng.execute_batch_async(
+                    [self.norm[i] for i in lp.engine_idx], plan=eng_plan)
 
         def _materialize() -> Tuple[List[np.ndarray], EngineStats]:
-            t1 = time.time()
-            results: List[Optional[np.ndarray]] = [None] * len(self.norm)
-            if pending is not None:
-                rows, stats = pending.materialize()
-                for i, r in zip(lp.engine_idx, rows):
-                    results[i] = r
-                if record:
-                    for arch, width in stats.knn_group_widths:
-                        p.qbs.record_convergence(arch, width)
-                    self.session.mp_scanned += stats.mp_scanned
-                    self.session.mp_rescued += stats.mp_rescued
-            else:
-                stats = EngineStats()
-            stats.queries = len(self.norm)
-            for i in lp.scalar_idx:
-                results[i] = p.execute(self.norm[i], record=False)[0]
-            stats.time_s = t_disp + (time.time() - t1)
+            with span("plan.execute") as epi:
+                results: List[Optional[np.ndarray]] = \
+                    [None] * len(self.norm)
+                if pending is not None:
+                    rows, stats = pending.materialize()
+                    for i, r in zip(lp.engine_idx, rows):
+                        results[i] = r
+                    if record:
+                        self.session.mp_scanned += stats.mp_scanned
+                        self.session.mp_rescued += stats.mp_rescued
+                else:
+                    stats = EngineStats()
+                stats.queries = len(self.norm)
+                for i in lp.scalar_idx:
+                    results[i] = p.execute(self.norm[i], record=False)[0]
+            stats.time_s = disp.seconds + epi.seconds
             if record:
-                reps: Dict[str, list] = {}
-                for q, frag in zip(self.norm, lp.fragments):
-                    slot = reps.setdefault(frag.signature, [q, 0])
-                    slot[1] += 1
-                for sig, (q, cnt) in reps.items():
-                    p.qbs.record_workload(sig, q, cnt)
+                self._feedback(stats, cost=False)
             return results, stats  # type: ignore[return-value]
 
         return PendingExecution(_materialize)

@@ -160,6 +160,7 @@ def topk_l2_pallas(q, p, k: int, *, bm: int = 128, bn: int = 512,
             jax.ShapeDtypeStruct((mp, kp), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_l2_pallas",
     )(q2, p2)
     bestd = bestd[:m, :kk]
     besti = besti[:m, :kk]
@@ -283,6 +284,7 @@ def topk_l2_masked_pallas(q, p, valid, k: int, *, bg: int = None,
             jax.ShapeDtypeStruct((gp, kp), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_l2_masked_pallas",
     )(*operands)
     bestd = bestd[:g, :kk]
     besti = jnp.where(jnp.isfinite(bestd), besti[:g, :kk], -1)
@@ -373,5 +375,6 @@ def quant_lb2_pallas(q, codes, cscale, cppq, ceps, valid, *, precision: str,
         out_specs=[pl.BlockSpec((bg, bc), lambda i, j: (i, j))],
         out_shape=[jax.ShapeDtypeStruct((gp, cp_), jnp.float32)],
         interpret=interpret,
+        name="quant_lb2_pallas",
     )(qc2, qm2, c2, cs2, cp2, ce2, v2)[0]
     return out[:g, :c]

@@ -35,6 +35,7 @@ from repro.configs.base import ModelConfig
 from repro.core import query as Q
 from repro.models import build_model
 from repro.serve.pipeline import ChunkPipeline
+from repro.utils.spans import Recorder, recording, span
 
 # Bound on the signature memo in RetrievalServer: keys are predicate
 # archetype strings (constants elided), so the live population is the
@@ -285,8 +286,10 @@ class RetrievalServer:
     per-request service time under its plan signature via
     ``QBSTable.record_latency`` — consumed by the predictive shed above
     and by ``ExecutablePlan.explain()``'s per-fragment latency block.
-    ``stats()`` reports served/shed/batch counters and per-signature
-    end-to-end p50/p99.
+    ``stats()`` reports served/shed/batch counters, per-signature
+    end-to-end p50/p99, and the spans and counters of recent chunks
+    (``trace``; the server's ``recorder`` is active while a chunk runs,
+    so the planner's and engine's spans land there too).
 
     Ordering contract: ``serve`` returns one ``RetrievalResult`` per
     request, POSITIONALLY in submission order, and a future always
@@ -416,6 +419,8 @@ class RetrievalServer:
         self.n_shed = 0
         self.n_batches = 0
         self._e2e: Dict[str, List[float]] = {}
+        # spans and counters of the chunks this server ran (stats()["trace"])
+        self.recorder = Recorder()
 
     # ------------------------------------------------------------ embedding
     def _embed_tokens(self, token_lists: Sequence[np.ndarray]) -> np.ndarray:
@@ -598,7 +603,8 @@ class RetrievalServer:
         t = self._clock() if now is None else now
         self._shed_expired(t)
         while self.queue_depth >= self.max_queue:
-            self.flush_one()          # backpressure: execute, never drop
+            # backpressure: execute, never drop
+            self.flush_one(reason="backpressure")
         fut = RetrievalFuture(self)
         dl = None if request.deadline_ms is None \
             else t + float(request.deadline_ms) / 1e3
@@ -625,11 +631,11 @@ class RetrievalServer:
         ``poll``/``flush`` — this is where the overlap engages under
         sustained load."""
         if self._pipe is None:
-            self.flush_one()
+            self.flush_one(reason="full")
             return
         if self._pipe.inflight >= self._pipe.depth:
             self._pipe.retire()
-        self._pipe.dispatch(self._next_chunk())
+        self._pipe.dispatch(self._next_chunk(), "full")
 
     def result(self, future: RetrievalFuture) -> RetrievalResult:
         """Resolve a future (flushing pending work if needed)."""
@@ -648,7 +654,7 @@ class RetrievalServer:
                 if self._pipe.inflight >= self._pipe.depth:
                     self._pipe.retire()
                 elif self._pickable():
-                    self._pipe.dispatch(self._next_chunk())
+                    self._pipe.dispatch(self._next_chunk(), "flush")
                 elif self._pipe.inflight:
                     self._pipe.retire()
                 else:
@@ -656,24 +662,25 @@ class RetrievalServer:
         while self._pending:
             self.flush_one()
 
-    def flush_one(self) -> int:
+    def flush_one(self, reason: str = "flush") -> int:
         """Shed expired work, then execute ONE micro-batch (the chunk
         ``_next_chunk`` picks), regardless of the batching window.
         Returns the number of requests served (0 when shedding emptied
         the queue). Pipelined mode dispatches one chunk when a stage
         slot is free, then retires the oldest in-flight chunk — one
         call still makes one chunk's worth of progress, so the
-        backpressure loop in ``submit`` shrinks the queue each call."""
+        backpressure loop in ``submit`` shrinks the queue each call.
+        ``reason`` is counted as why the chunk ran (``stats()``)."""
         self._shed_expired(self._clock())
         if self._pipe is not None:
             if self._pickable() and \
                     self._pipe.inflight < self._pipe.depth:
-                self._pipe.dispatch(self._next_chunk())
+                self._pipe.dispatch(self._next_chunk(), reason)
             return self._pipe.retire()
         if not self._pending:
             return 0
         chunk = self._next_chunk()
-        self._run_chunk(chunk)
+        self._run_chunk(chunk, reason)
         return len(chunk)
 
     def poll(self) -> int:
@@ -702,11 +709,12 @@ class RetrievalServer:
         self._shed_expired(now)
         if self._pipe is not None:
             return self._poll_pipelined(now)
-        if not self._pending or not self._due(now):
+        reason = self._due(now) if self._pending else None
+        if reason is None:
             self._reopt_step()
             return 0
         chunk = self._next_chunk()
-        self._run_chunk(chunk)
+        self._run_chunk(chunk, reason)
         self._reopt_step()
         return len(chunk)
 
@@ -715,9 +723,11 @@ class RetrievalServer:
         due chunk a free stage slot can take, retire the FIFO head, and
         use genuinely idle ticks for shape prewarming / reopt."""
         pipe = self._pipe
-        while (pipe.inflight < pipe.depth and self._pickable()
-               and self._due(now)):
-            pipe.dispatch(self._next_chunk())
+        while pipe.inflight < pipe.depth and self._pickable():
+            reason = self._due(now)
+            if reason is None:
+                break
+            pipe.dispatch(self._next_chunk(), reason)
         if pipe.inflight:
             served = pipe.retire()
             if pipe.inflight == 0:
@@ -763,31 +773,33 @@ class RetrievalServer:
             due.append(t if p.deadline is None else min(t, p.deadline))
         return min(due)
 
-    def _due(self, now: float) -> bool:
-        """Is a micro-batch worth running right now? (queue non-empty
-        is the caller's precondition) Per-signature windows: an entry
-        whose signature's window is exhausted (or zero) makes the
-        queue due, as does a deadline inside that window. Only entries
-        NOT already in flight count (serial mode: all of them)."""
+    def _due(self, now: float) -> Optional[str]:
+        """Why a micro-batch is worth running right now, or None when
+        none is (queue non-empty is the caller's precondition):
+        ``"full"`` when the queue or a signature group holds
+        ``batch_size``, ``"window"`` when an entry's signature window is
+        exhausted (or zero), ``"deadline"`` when a deadline falls inside
+        that window. Only entries NOT already in flight count (serial
+        mode: all of them)."""
         avail = self._pickable()
         if len(avail) >= self.batch_size:
-            return True
+            return "full"
         if self.coalesce:
             counts: Dict[str, int] = {}
             for p in avail:
                 counts[p.sig] = counts.get(p.sig, 0) + 1
                 if counts[p.sig] >= self.batch_size:
-                    return True
+                    return "full"
         win: Dict[str, float] = {}   # one QBS lookup per sig per pass
         for p in avail:
             if p.sig not in win:
                 win[p.sig] = self._window_s(p.sig)
             w = win[p.sig]
             if w <= 0 or now - p.t_submit >= w:
-                return True
+                return "window"
             if p.deadline is not None and p.deadline <= now + w:
-                return True
-        return False
+                return "deadline"
+        return None
 
     # ------------------------------------------------- re-optimization
     def attach_reopt(self, controller) -> None:
@@ -872,37 +884,54 @@ class RetrievalServer:
         # round DOWN to a power of two (the leftovers stay queued for
         # the next micro-batch), so per signature the engine only ever
         # compiles sizes {1, 2, 4, ..., batch_size}
-        take = self.batch_size if len(grp) >= self.batch_size \
-            else 2 ** int(math.log2(len(grp)))
+        if len(grp) >= self.batch_size:
+            take = self.batch_size
+        else:
+            take = 2 ** int(math.log2(len(grp)))
+            self.recorder.count("serve.rounded_off", len(grp) - take)
         return grp[:take]
 
     # ---------------------------------------------------------- execution
-    def _run_chunk(self, chunk: Sequence[_Pending]):
+    def _run_chunk(self, chunk: Sequence[_Pending], reason: str):
         """Execute one single-signature (coalesced) or mixed (FIFO)
         micro-batch, all-or-nothing: every result is computed and ranked
         before ANY future resolves or queue entry leaves ``_pending``.
         Past the mutation point nothing can raise (plain list/dict
         bookkeeping), so either the whole chunk resolves and dequeues,
-        or none of it does."""
+        or none of it does. The chunk runs under this server's
+        recorder, in the ``serve.*`` spans ``stats()`` reports."""
         reqs = [p.req for p in chunk]
         t0 = self._clock()
-        emb = self._embed_tokens([r.tokens for r in reqs])
-        queries = self._queries(reqs, emb)
-        rows, _ = self.session.plan(
-            queries, device_loop=self.device_loop).execute()
-        ranked = [self._ranked(req, e, r)
-                  for req, e, r in zip(reqs, emb, rows)]
-        self._finish_chunk(chunk, queries, ranked, t0)
+        with recording(self.recorder), \
+                span("serve.chunk", size=len(chunk), sig=chunk[0].sig):
+            with span("serve.embed"):
+                emb = self._embed_tokens([r.tokens for r in reqs])
+            with span("serve.queries"):
+                queries = self._queries(reqs, emb)
+            with span("serve.plan"):
+                plan = self.session.plan(queries,
+                                         device_loop=self.device_loop)
+            with span("serve.execute"):
+                rows, stats = plan.execute()
+            with span("serve.rank"):
+                ranked = [self._ranked(req, e, r)
+                          for req, e, r in zip(reqs, emb, rows)]
+            with span("serve.finish"):
+                self._finish_chunk(chunk, queries, ranked, t0, reason,
+                                   stats)
 
     def _finish_chunk(self, chunk: Sequence[_Pending], queries,
-                      ranked, t0: float) -> None:
+                      ranked, t0: float, reason: str, stats) -> None:
         """The shared mutation point for a fully-computed chunk:
         resolve futures, dequeue entries, record serving stats. Both
         the serial loop (``_run_chunk``) and the pipeline's retire
         stage (``ChunkPipeline.retire``) end here, so QBS latency /
-        e2e-ring writes are funneled through one code path regardless
-        of execution mode. Nothing here can raise (plain list/dict
-        bookkeeping), preserving the all-or-nothing contract."""
+        e2e-ring writes and the recorder's per-chunk counters (why the
+        chunk ran, its ``EngineStats``, each request's queue wait
+        ``t0 - t_submit``) are funneled through one code path
+        regardless of execution mode. Nothing here can raise (plain
+        list/dict bookkeeping), preserving the all-or-nothing
+        contract."""
         t1 = self._clock()
         per_req_s = (t1 - t0) / max(1, len(chunk))
         sig_counts: Dict[str, int] = {}
@@ -921,6 +950,18 @@ class RetrievalServer:
         self._pending = [p for p in self._pending if id(p) not in done]
         self.n_served += len(chunk)
         self.n_batches += 1
+        rec = self.recorder
+        start_ns = time.time_ns() - int((t1 - t0) * 1e9)  # t0, host clock
+        for p in chunk:
+            rec.sample("serve.queue_wait",
+                       max(0, int((t0 - p.t_submit) * 1e9)), start_ns)
+        rec.count("serve.chunks")
+        rec.count("serve.chunks." + reason)
+        rec.count("engine.queries", stats.queries)
+        rec.count("engine.rows_scanned", stats.rows_scanned)
+        rec.count("engine.knn_rounds", stats.knn_rounds)
+        rec.count("engine.knn_buckets", stats.knn_buckets)
+        rec.count("engine.predicate_buckets", stats.predicate_buckets)
 
     # ------------------------------------------------------------- sync
     def serve(self, requests: Sequence[RetrievalRequest]
@@ -935,7 +976,28 @@ class RetrievalServer:
         table, see ``QBSTable.latency_quantiles``). ``generation`` /
         ``build_id`` identify the index generation currently serving;
         ``reopt`` is the attached controller's progress (None when no
-        controller is attached)."""
+        controller is attached).
+
+        ``trace`` is the operator's record of the most recent chunks:
+        ``{"spans": {name: {"n": total, "ring": [[start_ns, dur_ns],
+        ...]}}, "counters": {name: value}, "time_minus_monotonic_ns":
+        offset}`` (``repro.utils.spans``; ``start_ns`` on the
+        profiler's host clock, the last 16,384 entries per name; the
+        offset maps it onto ``time.monotonic_ns()`` as of this call). Spans: ``serve.chunk`` from chunk pick to the
+        last future resolved, its stages ``serve.embed``,
+        ``serve.queries``, ``serve.plan``, ``serve.execute``,
+        ``serve.rank``, ``serve.finish`` (pipelined mode:
+        ``serve.dispatch`` and ``serve.retire`` around them, and
+        ``serve.execute`` twice, dispatch and epilogue), the engine's
+        and planner's spans inside, and ``serve.queue_wait``, one
+        sample per served request (chunk start less arrival). Counters:
+        ``serve.chunks`` and ``serve.chunks.<why>`` (``full``,
+        ``window``, ``deadline``, ``flush``, ``backpressure``),
+        ``serve.rounded_off`` (requests a partial group's power-of-two
+        round-down left queued), and the chunks' summed ``EngineStats``
+        (``engine.queries``, ``engine.rows_scanned``,
+        ``engine.knn_rounds``, ``engine.knn_buckets``,
+        ``engine.predicate_buckets``)."""
         by_sig = {}
         for sig, ls in self._e2e.items():
             a = np.asarray(ls, np.float64)
@@ -951,4 +1013,5 @@ class RetrievalServer:
                 "build_id": self.platform.build_id,
                 "reopt": None if self.reopt is None
                 else self.reopt.status(),
-                "by_signature": by_sig}
+                "by_signature": by_sig,
+                "trace": self.recorder.snapshot()}

@@ -58,23 +58,31 @@ instead of stalling the pipeline on a cold trace+compile.
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from typing import Deque, List, Optional, Sequence, Set, Tuple
+
+from repro.utils.spans import recording, span
 
 
 class _InflightChunk:
     """One dispatched micro-batch: its queue entries, staged inputs,
     and the deferred epilogue handle."""
 
-    __slots__ = ("chunk", "reqs", "emb", "queries", "pending", "t0")
+    __slots__ = ("chunk", "reqs", "emb", "queries", "pending", "t0",
+                 "reason", "start_ns", "p0")
 
-    def __init__(self, chunk, reqs, emb, queries, pending, t0):
+    def __init__(self, chunk, reqs, emb, queries, pending, t0, reason,
+                 start_ns, p0):
         self.chunk = chunk
         self.reqs = reqs
         self.emb = emb
         self.queries = queries
         self.pending = pending
         self.t0 = t0
+        self.reason = reason          # why the chunk ran (server counters)
+        self.start_ns = start_ns      # dispatch start, host clock
+        self.p0 = p0                  # dispatch start, perf_counter_ns
 
 
 class ChunkPipeline:
@@ -104,21 +112,31 @@ class ChunkPipeline:
         return len(self._inflight)
 
     # ------------------------------------------------------------ stages
-    def dispatch(self, chunk: Sequence) -> None:
+    def dispatch(self, chunk: Sequence, reason: str) -> None:
         """Stages 1+2 for one chunk: embed + build queries (host), then
         enqueue the planned execution on the device and append the
         chunk to the in-flight FIFO. On ANY raise the chunk's entries
         stay pending and unmarked (nothing was appended), so the next
-        flush retries them — in-flight chunks are unaffected."""
+        flush retries them — in-flight chunks are unaffected.
+        ``reason`` is why the chunk ran, counted when it retires."""
         srv = self.server
         reqs = [p.req for p in chunk]
         t0 = srv._clock()
-        emb = srv._embed_tokens([r.tokens for r in reqs])
-        queries = srv._queries(reqs, emb)
-        pending = srv.session.plan(
-            queries, device_loop=srv.device_loop).execute_async()
+        start_ns, p0 = time.time_ns(), time.perf_counter_ns()
+        with recording(srv.recorder), \
+                span("serve.dispatch", size=len(chunk), sig=chunk[0].sig):
+            with span("serve.embed"):
+                emb = srv._embed_tokens([r.tokens for r in reqs])
+            with span("serve.queries"):
+                queries = srv._queries(reqs, emb)
+            with span("serve.plan"):
+                plan = srv.session.plan(queries,
+                                        device_loop=srv.device_loop)
+            with span("serve.execute"):
+                pending = plan.execute_async()
         self._inflight.append(_InflightChunk(
-            list(chunk), reqs, emb, queries, pending, t0))
+            list(chunk), reqs, emb, queries, pending, t0, reason,
+            start_ns, p0))
         srv._mark_inflight(chunk)
         self._note_shape(chunk, queries)
 
@@ -137,17 +155,26 @@ class ChunkPipeline:
             return 0
         srv = self.server
         ent = self._inflight[0]
-        try:
-            rows, _ = ent.pending.materialize()
-            ranked = [srv._ranked(req, e, r) for req, e, r in
-                      zip(ent.reqs, ent.emb, rows)]
-        except BaseException:
+        with recording(srv.recorder), span("serve.retire"):
+            try:
+                with span("serve.execute"):
+                    rows, stats = ent.pending.materialize()
+                with span("serve.rank"):
+                    ranked = [srv._ranked(req, e, r) for req, e, r in
+                              zip(ent.reqs, ent.emb, rows)]
+            except BaseException:
+                self._inflight.popleft()
+                srv._unmark_inflight(ent.chunk, requeue=True)
+                raise
             self._inflight.popleft()
-            srv._unmark_inflight(ent.chunk, requeue=True)
-            raise
-        self._inflight.popleft()
-        srv._unmark_inflight(ent.chunk)
-        srv._finish_chunk(ent.chunk, ent.queries, ranked, ent.t0)
+            srv._unmark_inflight(ent.chunk)
+            with span("serve.finish"):
+                srv._finish_chunk(ent.chunk, ent.queries, ranked, ent.t0,
+                                  ent.reason, stats)
+        # the chunk's whole life, dispatch to resolution: chunks overlap
+        # here, so it is recorded as an interval, not entered as a span
+        srv.recorder.add("serve.chunk", ent.start_ns,
+                         time.perf_counter_ns() - ent.p0)
         return len(ent.chunk)
 
     def drain(self) -> int:

@@ -229,6 +229,27 @@ def test_batched_knn_device_matches_host_and_oracle(platform):
                 assert set(rd[i][rd[i] >= 0].tolist()) == want
 
 
+def test_mask_rows_may_stop_short_of_the_queries(platform):
+    """A group's masked jobs come first and only their rows are passed:
+    the unmasked tail's all-true rows are built on device, and both
+    beam loops return what a full mask array gives."""
+    p = platform
+    eng = p.engine()
+    col = p.table.vector["img"]
+    qs = col[[3, 70, 500, 900, 1500, 2200]].astype(np.float32) + 0.2
+    mask = p.table.numeric["price"] < 35.0
+    part = np.broadcast_to(mask, (2, len(mask)))
+    full = np.concatenate([part, np.ones((4, len(mask)), bool)])
+    for knn, geom, tiles in (
+            (batched_knn, eng.geom["img"], eng.vec_tiles["img"]),
+            (batched_knn_device, eng.geom_dev["img"],
+             eng.vec_tiles_dev["img"])):
+        _, want = knn(geom, tiles, qs, 8, masks=full, beam=4)
+        _, got = knn(geom, tiles, qs, 8, masks=part, beam=4)
+        assert np.array_equal(got, want)
+        assert not np.isin(got[:2], np.nonzero(~mask)[0]).any()
+
+
 def test_device_loop_empty_mask(platform):
     """A filter admitting zero rows: the device loop returns no rows
     instead of looping to the budget."""

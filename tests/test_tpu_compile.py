@@ -10,7 +10,9 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and under several test
 workers only the worker that runs this file loads it.
 """
+import ast
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,8 @@ from repro.kernels.lpgf_force import lpgf_force_pallas
 from repro.kernels.pairwise_l2 import pairwise_sq_l2_pallas
 
 G, C = 64, 4096          # one engine round: 64 queries x 64 tiles of 64 rows
+BENCH_KERNELS = os.path.join(os.path.dirname(__file__), "..", "bench",
+                             "harness", "kernels.py")
 
 
 @pytest.fixture(scope="module")
@@ -96,3 +100,47 @@ def test_pairwise_sq_l2_compiles(one_chip, d):
 def test_lpgf_force_compiles(one_chip):
     _compile(lambda x: lpgf_force_pallas(x, 7.5, 1.0), one_chip,
              ((8192, 128), jnp.float32))
+
+
+def _bench_topk_names():
+    """``TOPK`` of the benchmark's kernel table: the instruction names
+    its trace reduction matches (read, not imported)."""
+    with open(BENCH_KERNELS) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and \
+                any(getattr(t, "id", None) == "TOPK" for t in node.targets):
+            return set(ast.literal_eval(node.value))
+    raise AssertionError("bench/harness/kernels.py defines no TOPK")
+
+
+def _custom_calls(compiled):
+    """The instruction names of the compiled program's custom calls,
+    without their ``.N`` suffix."""
+    return {re.sub(r"\.\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom-call\(", compiled.as_text())}
+
+
+@pytest.mark.parametrize("kernel", ["topk_l2_pallas", "topk_l2_masked_pallas",
+                                    "quant_lb2_pallas"])
+def test_kernel_instruction_names(one_chip, kernel):
+    """Each kernel's custom call is named as the device trace shows it,
+    and the top-k ones as the benchmark's ``TOPK`` expects."""
+    d = 128
+    if kernel == "topk_l2_pallas":
+        compiled = _compile(lambda q, p: topk_l2_pallas(q, p, 10), one_chip,
+                            ((256, d), jnp.float32), ((4096, d), jnp.float32))
+    elif kernel == "topk_l2_masked_pallas":
+        compiled = _compile(
+            lambda q, p, v: topk_l2_masked_pallas(q, p, v, 10), one_chip,
+            ((G, d), jnp.float32), ((G, C, d), jnp.float32),
+            ((G, C), jnp.bool_))
+    else:
+        compiled = _compile(
+            lambda *a: quant_lb2_pallas(*a, precision="int8"), one_chip,
+            ((G, d), jnp.float32), ((G, C, d), jnp.int8),
+            ((G, C), jnp.float32), ((G, C), jnp.float32),
+            ((G, C), jnp.float32), ((G, C), jnp.bool_))
+    assert _custom_calls(compiled) == {kernel}
+    if kernel.startswith("topk"):
+        assert kernel in _bench_topk_names()
