@@ -231,6 +231,11 @@ class EngineStats:
     # rescued/scanned is the rescue ratio explain() reports
     mp_scanned: int = 0
     mp_rescued: int = 0
+    # top-k kernel work (fp32 scan): grid steps that computed distances,
+    # and the merge passes they ran — one per candidate inserted into a
+    # running top-k, so passes / blocks is the merge's cost per block
+    topk_blocks: int = 0
+    topk_merge_passes: int = 0
     time_s: float = 0.0
     # (archetype, converged width in tiles) per executed KNN group — the
     # feedback signal Session records into QBS for query-aware seeding
@@ -255,7 +260,8 @@ def _knn_round(act, qs, order, masks_tiles, data_tiles, bucket_rows,
     """One beam round for the ``act`` query subset: scan each query's
     [w0, w1) best-lower-bound buckets with the fused distance+top-k kernel.
     Returns (sq_dists, physical rows, number of valid candidate rows,
-    fp32-rescued candidate count — 0 on the fp32 path).
+    fp32-rescued candidate count — 0 on the fp32 path, the kernel's
+    (grid steps, merge passes) — 0 on the mixed-precision path).
     Rounds are incremental — the host merges each round's top-k with the
     carry from earlier buckets. ``data_tiles`` is the (T, cap, d)
     tile-major copy of the table column: candidate gathers move whole
@@ -286,15 +292,16 @@ def _knn_round(act, qs, order, masks_tiles, data_tiles, bucket_rows,
         d2, idx, resc = ops.topk_l2_masked_mp(
             qa, sel, valid, data_tiles, *planes, k, lb2=lb2, kth0=kth0,
             precision=precision, interpret=interpret)
+        work = jnp.zeros(2, jnp.int32)
     else:
         pts = jnp.take(data_tiles, sel, axis=0)          # (G, w, cap, d)
         pts = pts.reshape(g, -1, pts.shape[-1])          # (G, w*cap, d)
-        d2, idx = ops.topk_l2_masked(qa, pts, valid, k,
-                                     interpret=interpret)
+        d2, idx, work = ops.topk_l2_masked(qa, pts, valid, k,
+                                           interpret=interpret)
         resc = jnp.zeros(g, jnp.int32)
     rows = jnp.take_along_axis(cand, jnp.maximum(idx, 0), axis=1)
     rows = jnp.where(idx >= 0, rows, -1)
-    return d2, rows, jnp.sum(valid, axis=1), resc
+    return d2, rows, jnp.sum(valid, axis=1), resc, work
 
 
 def _device_masks(masks, g: int):
@@ -394,7 +401,7 @@ def batched_knn(geom: LeafGeometry, data_tiles, qs, k: int, *,
             # host carry's kth SQUARED distance tightens the mp round's
             # refutation from its first rescue iteration
             kth0_all = jnp.asarray(best_d2[:, -1])
-        d2, rows, nvalid, resc = _knn_round(
+        d2, rows, nvalid, resc, work = _knn_round(
             jnp.asarray(padded), qs, order, masks_tiles,
             data_tiles, geom.bucket_rows, planes, lb_dev, kth0_all,
             w0=w0, w1=w, k=k, precision=precision, interpret=interpret)
@@ -406,6 +413,7 @@ def batched_knn(geom: LeafGeometry, data_tiles, qs, k: int, *,
             stats.knn_buckets += na * (w - w0)
             nv = int(np.asarray(nvalid)[:na].sum())
             stats.rows_scanned += nv
+            _count_topk(stats, work)
             if precision != "fp32":
                 stats.mp_scanned += nv
                 stats.mp_rescued += int(np.asarray(resc)[:na].sum())
@@ -453,7 +461,8 @@ def _knn_device_loop(idx, active0, qs_full, d2_full, rows_full, order,
     remaining visit order (columns past ``w1``), padded to the loop's
     static budget*w width with 0-columns whose +inf lower bound kills
     them. Returns (best_d2, best_rows, [rounds, buckets_scanned,
-    rows_scanned], per-query retirement round)."""
+    rows_scanned, rescued, top-k grid steps, top-k merge passes],
+    per-query retirement round)."""
     l = order.shape[1]
     qs = jnp.take(qs_full, idx, axis=0)
     bd0 = jnp.take(d2_full, idx, axis=0)
@@ -472,7 +481,7 @@ def _knn_device_loop(idx, active0, qs_full, d2_full, rows_full, order,
         return (r < budget) & jnp.any(active)
 
     def body(st):
-        r, active, bd, br, nbuck, nrows, nresc, rr = st
+        r, active, bd, br, nbuck, nrows, nresc, work, rr = st
         start = r * w
         sel = jax.lax.dynamic_slice_in_dim(order_pad, start, w, axis=1)
         lb_col = jax.lax.dynamic_slice_in_dim(lb_pad, start, w, axis=1)
@@ -498,10 +507,14 @@ def _knn_device_loop(idx, active0, qs_full, d2_full, rows_full, order,
                 qs, sel, valid, data_tiles, *planes, k, lb2=lb2,
                 kth0=bd[:, -1], precision=precision, interpret=interpret)
         else:
+            # seeded with the carry's kth: only candidates that can
+            # enter the merged top-k below are inserted by the kernel
             pts = jnp.take(data_tiles, sel, axis=0)      # (G, w, cap, d)
             pts = pts.reshape(g, -1, pts.shape[-1])
-            d2, idx = ops.topk_l2_masked(qs, pts, valid, k,
-                                         interpret=interpret, lb2=lb2)
+            d2, idx, kwork = ops.topk_l2_masked(
+                qs, pts, valid, k, interpret=interpret, lb2=lb2,
+                kth0=bd[:, -1])
+            work = work + kwork
             resc = jnp.zeros(g, jnp.int32)
         rows = jnp.take_along_axis(cand, jnp.maximum(idx, 0), axis=1)
         rows = jnp.where(idx >= 0, rows, -1)
@@ -523,15 +536,16 @@ def _knn_device_loop(idx, active0, qs_full, d2_full, rows_full, order,
         nbuck = nbuck + jnp.sum(jnp.where(active[:, None], colv, False))
         nrows = nrows + jnp.sum(valid)
         nresc = nresc + jnp.sum(resc)
-        return r + 1, active2, md, mr, nbuck, nrows, nresc, rr
+        return r + 1, active2, md, mr, nbuck, nrows, nresc, work, rr
 
     st0 = (jnp.int32(0), active0, bd0, br0,
            jnp.int32(0), jnp.int32(0), jnp.int32(0),
-           jnp.zeros(g, jnp.int32))
-    r, act_f, bd, br, nbuck, nrows, nresc, rr = \
+           jnp.zeros(2, jnp.int32), jnp.zeros(g, jnp.int32))
+    r, act_f, bd, br, nbuck, nrows, nresc, work, rr = \
         jax.lax.while_loop(cond, body, st0)
     rr = jnp.where(act_f, r, rr)  # budget-exhausted: scanned everything
-    return bd, br, jnp.stack([r, nbuck, nrows, nresc]), rr
+    return bd, br, jnp.concatenate([jnp.stack([r, nbuck, nrows, nresc]),
+                                    work]), rr
 
 
 _ROUND_BYTES = 256 << 20   # cap on one straggler round's gathered fp32
@@ -595,7 +609,7 @@ def _knn_start(qs, masks_tiles, centroid, radius, data_tiles,
         else _knn_prologue
     order, lb_sorted = prologue(qs, centroid, radius, masks_tiles)
     l = lb_sorted.shape[1]
-    d2, rows, nvalid, resc = _knn_round(
+    d2, rows, nvalid, resc, work = _knn_round(
         jnp.arange(g, dtype=jnp.int32), qs, order, masks_tiles,
         data_tiles, bucket_rows, planes, lb_sorted, None,
         w0=0, w1=w1, k=k, precision=precision, interpret=interpret)
@@ -603,7 +617,28 @@ def _knn_start(qs, masks_tiles, centroid, radius, data_tiles,
     nxt = lb_sorted[:, w1] if w1 < l else \
         jnp.full(g, jnp.inf, jnp.float32)
     return (order, lb_sorted, d2, rows, kth > nxt, jnp.sum(nvalid),
-            jnp.sum(resc))
+            jnp.sum(resc), work)
+
+
+def _count_topk(stats: EngineStats, work) -> None:
+    """Add a round's top-k kernel (grid steps, merge passes)."""
+    blocks, passes = np.asarray(work)
+    stats.topk_blocks += int(blocks)
+    stats.topk_merge_passes += int(passes)
+
+
+def _count_loop(stats: EngineStats, loop_stats: np.ndarray,
+                precision: str) -> None:
+    """Add a straggler loop's ``loop_stats`` (rounds, tiles, rows,
+    rescued, top-k grid steps, merge passes) to ``stats``."""
+    rounds, nbuck, nrows, nresc = (int(x) for x in loop_stats[:4])
+    stats.knn_rounds += rounds
+    stats.knn_buckets += nbuck
+    stats.rows_scanned += nrows
+    if precision != "fp32":
+        stats.mp_scanned += nrows
+        stats.mp_rescued += nresc
+    _count_topk(stats, loop_stats[4:])
 
 
 def _start_d2h(a) -> None:
@@ -668,11 +703,11 @@ def batched_knn_device_async(geom: LeafGeometry, data_tiles, qs, k: int,
                                       geom.bucket_rows)
         l = geom.n_leaves
         w1 = max(1, min(w1 if w1 else max(1, beam // 2), l))
-        order, lb_sorted, d2, rows, active, nvalid, resc = _knn_start(
-            qs, masks_tiles, geom.centroid, geom.radius, data_tiles,
-            geom.bucket_rows, planes, w1=w1, k=k, precision=precision,
-            interpret=interpret)
-        for a in (active, d2, rows, nvalid, resc):
+        order, lb_sorted, d2, rows, active, nvalid, resc, work = \
+            _knn_start(qs, masks_tiles, geom.centroid, geom.radius,
+                       data_tiles, geom.bucket_rows, planes, w1=w1, k=k,
+                       precision=precision, interpret=interpret)
+        for a in (active, d2, rows, nvalid, resc, work):
             _start_d2h(a)
 
     def _finish() -> Tuple[np.ndarray, np.ndarray]:
@@ -681,11 +716,13 @@ def batched_knn_device_async(geom: LeafGeometry, data_tiles, qs, k: int,
             d2f = np.asarray(d2, dtype=np.float32)
             rowsf = np.asarray(rows)
             nv, nr = int(nvalid), int(resc)
+            work1 = np.asarray(work)
         host_s = disp.seconds + fence.seconds
         if stats is not None:
             stats.knn_rounds += 1
             stats.knn_buckets += g * w1
             stats.rows_scanned += nv
+            _count_topk(stats, work1)
             if precision != "fp32":
                 stats.mp_scanned += nv
                 stats.mp_rescued += nr
@@ -709,19 +746,14 @@ def batched_knn_device_async(geom: LeafGeometry, data_tiles, qs, k: int,
             with span("engine.straggler_fence") as sfence:
                 bd, br = np.asarray(bd)[:na], np.asarray(br)[:na]
                 retired = np.asarray(retire_round)[:na]
-                rounds, nbuck, nrows, nresc = np.asarray(loop_stats)
+                loop_stats = np.asarray(loop_stats)
             host_s += strag.seconds + sfence.seconds
             d2f, rowsf = d2f.copy(), rowsf.copy()
             d2f[act] = bd
             rowsf[act] = br
             conv[act] = np.minimum(w1 + retired.astype(np.int64) * w, l)
             if stats is not None:
-                stats.knn_rounds += int(rounds)
-                stats.knn_buckets += int(nbuck)
-                stats.rows_scanned += int(nrows)
-                if precision != "fp32":
-                    stats.mp_scanned += int(nrows)
-                    stats.mp_rescued += int(nresc)
+                _count_loop(stats, loop_stats, precision)
         if stats is not None:
             stats.time_s += host_s
         if conv_out is not None:
@@ -1023,11 +1055,12 @@ def _sharded_local_scan(qs, sel, colv, act, lbd, lbr, br_all, dt_all,
                         planes=None, precision: str = "fp32", kth0=None):
     """One shard's beam scan of its selected local tiles, merged into
     its LOCAL heap (stable: carry first, so earlier lower-bound tiles
-    keep the visit-order tie-break). With ``precision`` != "fp32",
-    ``planes`` holds the shard's assembled (base + delta) quantized
-    arrays and ``kth0`` the previous round's GLOBAL kth squared
-    distance; returns an extra scalar — this shard's fp32-rescued
-    candidate count."""
+    keep the visit-order tie-break); the fp32 kernel is seeded with the
+    local heap's kth. With ``precision`` != "fp32", ``planes`` holds the
+    shard's assembled (base + delta) quantized arrays and ``kth0`` the
+    previous round's GLOBAL kth squared distance. Returns the heap, the
+    shard's valid candidate count, its fp32-rescued candidate count and
+    its top-k kernel (grid steps, merge passes)."""
     g = qs.shape[0]
     cap = br_all.shape[1]
     cand = br_all[sel].reshape(g, -1)
@@ -1044,11 +1077,13 @@ def _sharded_local_scan(qs, sel, colv, act, lbd, lbr, br_all, dt_all,
             qs, sel, valid, dt_all, *planes, k, lb2=lb2, kth0=kth0,
             precision=precision, interpret=interpret)
         nresc = jnp.sum(resc)
+        work = jnp.zeros(2, jnp.int32)
     else:
         pts = jnp.take(dt_all, sel, axis=0).reshape(g, -1,
                                                     dt_all.shape[-1])
-        d2, idx = ops.topk_l2_masked(qs, pts, valid, k,
-                                     interpret=interpret, lb2=lb2)
+        d2, idx, work = ops.topk_l2_masked(qs, pts, valid, k,
+                                           interpret=interpret, lb2=lb2,
+                                           kth0=lbd[:, -1])
         nresc = jnp.int32(0)
     rows = jnp.take_along_axis(cand, jnp.maximum(idx, 0), axis=1)
     rows = jnp.where(idx >= 0, rows, -1)
@@ -1056,7 +1091,7 @@ def _sharded_local_scan(qs, sel, colv, act, lbd, lbr, br_all, dt_all,
     allr = jnp.concatenate([lbr, rows], axis=1)
     negd, pick = jax.lax.top_k(-alld, k)
     return -negd, jnp.take_along_axis(allr, pick, axis=1), \
-        jnp.sum(valid), nresc
+        jnp.sum(valid), nresc, work
 
 
 @functools.lru_cache(maxsize=None)
@@ -1120,7 +1155,7 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
         bd0 = jnp.full((g, k), jnp.inf, jnp.float32)
         br0 = jnp.full((g, k), -1, jnp.int32)
         colv = ~jnp.isinf(lb_l[:, :w1])
-        lbd, lbr, nvalid, nresc = _sharded_local_scan(
+        lbd, lbr, nvalid, nresc, work = _sharded_local_scan(
             qs, order_l[:, :w1], colv, None, bd0, br0, br, dt, mt, k,
             interpret, lb_col=lb_l[:, :w1] if mp else None,
             planes=planes, precision=precision)
@@ -1130,7 +1165,8 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
         nxt = jax.lax.pmin(nxt, "shards")
         return (order_l, lb_l, mt, lbd, lbr, gbd, gbr, kth > nxt,
                 jax.lax.psum(nvalid, "shards"),
-                jax.lax.psum(nresc, "shards"))
+                jax.lax.psum(nresc, "shards"),
+                jax.lax.psum(work, "shards"))
 
     start_fn = jax.jit(manual_shard_map(
         start, mesh,
@@ -1142,7 +1178,7 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
         out_specs=(P(None, "shards"), P(None, "shards"),
                    P(None, "shards", None), P(None, "shards"),
                    P(None, "shards"), P(None, None), P(None, None),
-                   P(None), P(), P()),
+                   P(None), P(), P(), P(None)),
         manual_axes=("shards",)))
 
     def loop(idx, active0, qs_f, lbd_f, lbr_f, order_f, lb_f, mt_f,
@@ -1169,14 +1205,15 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
             return (st[0] < budget) & jnp.any(st[1])
 
         def body(st):
-            r, act, gbd, _, lbd, lbr, nbuck, nrows, nresc_a, rr = st
+            (r, act, gbd, _, lbd, lbr, nbuck, nrows, nresc_a, work_a,
+             rr) = st
             start_ = r * w
             sel = jax.lax.dynamic_slice_in_dim(order_pad, start_, w,
                                                axis=1)
             lb_col = jax.lax.dynamic_slice_in_dim(lb_pad, start_, w,
                                                   axis=1)
             colv = ~jnp.isinf(lb_col)
-            lbd2, lbr2, nv, nresc = _sharded_local_scan(
+            lbd2, lbr2, nv, nresc, work = _sharded_local_scan(
                 qs, sel, colv, act, lbd, lbr, br, dt, mt, k, interpret,
                 lb_col=lb_col, planes=planes, precision=precision,
                 kth0=gbd[:, -1] if mp else None)
@@ -1190,16 +1227,18 @@ def _sharded_knn_fns(mesh, t_local: int, td: int, cap: int, w1: int,
                 jnp.sum(jnp.where(act[:, None], colv, False)), "shards")
             nrows = nrows + jax.lax.psum(nv, "shards")
             nresc_a = nresc_a + jax.lax.psum(nresc, "shards")
+            work_a = work_a + jax.lax.psum(work, "shards")
             return (r + 1, act2, gbd2, gbr2, lbd2, lbr2, nbuck, nrows,
-                    nresc_a, rr)
+                    nresc_a, work_a, rr)
 
         st0 = (jnp.int32(0), active0, gbd0, gbr0, lbd, lbr,
                jnp.int32(0), jnp.int32(0), jnp.int32(0),
-               jnp.zeros(g, jnp.int32))
-        r, act_f, gbd, gbr, _, _, nbuck, nrows, nresc, rr = \
+               jnp.zeros(2, jnp.int32), jnp.zeros(g, jnp.int32))
+        r, act_f, gbd, gbr, _, _, nbuck, nrows, nresc, work, rr = \
             jax.lax.while_loop(cond, body, st0)
         rr = jnp.where(act_f, r, rr)
-        return gbd, gbr, jnp.stack([r, nbuck, nrows, nresc]), rr
+        return gbd, gbr, jnp.concatenate(
+            [jnp.stack([r, nbuck, nrows, nresc]), work]), rr
 
     loop_fn = jax.jit(manual_shard_map(
         loop, mesh,
@@ -1269,7 +1308,7 @@ def batched_knn_sharded(st: ShardedTiles, qs, k: int, *,
     mtm = shard_put(mtm_np, st.mesh, P(None, "shards", None))
     dmtm = shard_put(dmtm_np, st.mesh, P(None, None, None))
     (order_f, lb_f, mt_f, lbd, lbr, gbd, gbr, active, nvalid,
-     nresc) = start_fn(
+     nresc, work) = start_fn(
         qs_j, mtm, dmtm, st.centroid, st.radius, st.bucket_rows,
         st.data_tiles, st.d_centroid, st.d_radius, st.d_bucket_rows,
         st.d_data_tiles, *qp)
@@ -1277,6 +1316,7 @@ def batched_knn_sharded(st: ShardedTiles, qs, k: int, *,
         stats.knn_rounds += 1
         stats.knn_buckets += g * w1 * s
         stats.rows_scanned += int(nvalid)
+        _count_topk(stats, work)
         if precision != "fp32":
             stats.mp_scanned += int(nvalid)
             stats.mp_rescued += int(nresc)
@@ -1302,13 +1342,7 @@ def batched_knn_sharded(st: ShardedTiles, qs, k: int, *,
         conv[act] = np.minimum(
             w1 + np.asarray(retire_round)[:na].astype(np.int64) * w, l)
         if stats is not None:
-            rounds, nbuck, nrows, nresc_l = np.asarray(loop_stats)
-            stats.knn_rounds += int(rounds)
-            stats.knn_buckets += int(nbuck)
-            stats.rows_scanned += int(nrows)
-            if precision != "fp32":
-                stats.mp_scanned += int(nrows)
-                stats.mp_rescued += int(nresc_l)
+            _count_loop(stats, np.asarray(loop_stats), precision)
     if stats is not None:
         stats.time_s += time.time() - t0
     if conv_out is not None:

@@ -6,10 +6,16 @@ VMEM scratch, so HBM traffic is O(M*D + N*D + M*k) instead of O(M*N).
 
 Grid: (M/BM, N/BN) with the N axis INNERMOST and "arbitrary" semantics —
 each (i, j) step merges tile-j candidates into query tile i's running
-buffer. The merge keeps the best k of (k + BN) candidates by k passes of
-lane-wise min extraction over the buffer (k padded to a lane multiple)
-and the new block — no sort and no gather, which Mosaic does not lower —
-with a stable tie-break: buffer entries first, then lower index.
+buffer, a sorted (BM, KP) pair of distances and indices (k padded to a
+lane multiple). The merge does work in proportion to the candidates
+that can still enter the top-k, not to k: a candidate survives only if
+``d < min(kth0, running kth)``, and the step runs one insertion pass per
+survivor of its fullest row (at most k) — extract the block's smallest
+survivor, find its slot in the sorted buffer, shift the tail one lane —
+with no sort and no gather, which Mosaic does not lower. Tie-break law
+(that of a stable sort): buffer entries first, then lower index. A
+query that has seen N candidates has seen about k ln(N/k) of them beat
+its running kth, so most steps run no pass at all.
 
 Two variants share the merge scheme:
   * ``topk_l2_pallas``        — one shared point set for all queries
@@ -18,17 +24,26 @@ Two variants share the merge scheme:
     bucket beam gathered, and filtered KNN (And(VK, predicate)) stays
     fused by zeroing the mask instead of re-gathering.
 
+Threshold seed (``kth0``): the masked variant optionally takes each
+query's kth SQUARED distance of the carry its output is merged into.
+Every caller merges the kernel's top-k into that carry with a stable
+``top_k``, carry first, so a candidate at or above the carry's kth can
+never displace it (a tie goes to the carry): dropping it changes the
+kernel's own output but not the merged result. From its first block the
+call then inserts only what can reach the merged top-k, instead of
+filling an empty buffer with k entries.
+
 Tile early-out (``lb2``): the masked variant optionally takes per-candidate
 SQUARED ball lower bounds (each candidate row carries its bucket tile's
 ``max(0, |q - C| - R)^2``). A grid step whose every valid candidate has
-``lb2 >= running kth distance`` cannot change any query's top-k — a lower
-bound at or above the kth squared distance proves the true distance can
-only tie, and ties never displace the (stable) running buffer — so the
-whole distance + merge body is skipped under ``@pl.when``. Beam rounds
-select tiles in ascending-bound order per query, so once a query
-converges, the straggler tiles other queries still need stop charging it:
-blocks whose candidates are all bound-refuted (or masked/padding) become
-no-ops instead of full GEMM + sort-network steps.
+``lb2 >= min(kth0, running kth)`` cannot change the merged top-k — a
+lower bound at or above the threshold proves the true distance can only
+tie, and ties never displace the (stable) running buffer or carry — so
+the whole distance + merge body is skipped under ``@pl.when``. Beam
+rounds select tiles in ascending-bound order per query, so once a query
+converges, the straggler tiles other queries still need stop charging
+it: blocks whose candidates are all bound-refuted (or masked/padding)
+become no-ops instead of full GEMM + merge steps.
 
 Mixed-precision scan (``quant_lb2_pallas``): computes the per-candidate
 widened bounds themselves in reduced precision. The candidate tiles are
@@ -49,6 +64,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pairwise_l2 import _pad
 
@@ -77,33 +93,49 @@ def _kth(bestd, k: int):
                    keepdims=True)
 
 
-def _merge_topk(bestd, besti, d, idx, k: int):
-    """The k smallest of the running buffer (B, KP) and a new block
-    (B, BC), by k passes of lane-wise min extraction — no sort, no
-    gather, so Mosaic lowers it. Tie-break law (that of a stable sort):
-    buffer entries before new ones, then lower position first. Slots at
-    and past k hold +inf; a slot whose distance is +inf carries an
-    arbitrary index (callers map non-finite slots to -1)."""
-    alld = jnp.concatenate([bestd, d], axis=1)
-    alli = jnp.concatenate([besti, idx], axis=1)
-    width = alld.shape[1]
-    pos = jax.lax.broadcasted_iota(jnp.int32, alld.shape, 1)
+def _insert_survivors(bestd, besti, d, idx0, thr, k: int):
+    """Merge a new block (B, BC) into the sorted running buffer (B, KP):
+    the block's candidates with ``d < thr`` (``thr`` (B, 1) at most the
+    buffer's kth, so only they can enter the top-k) are inserted in
+    ascending (distance, index) order, one pass each, for as many passes
+    as the fullest row has survivors, capped at k — a row with more than
+    k survivors keeps only its k smallest anyway. A pass extracts the
+    row's smallest survivor, places it after every buffer entry at or
+    below it (the tie-break law of a stable sort: buffer entries first,
+    then lower index, since earlier survivors of the block are in the
+    buffer already), and shifts the tail one lane with ``pltpu.roll``.
+    A row out of survivors extracts +inf, whose slot is past the buffer:
+    its pass changes nothing. Slots at and past k may hold finite
+    entries pushed out of the top-k; a slot whose distance is +inf
+    carries an arbitrary index (callers map non-finite slots to -1).
+    Block candidate ``c`` has index ``idx0 + c``. Returns the buffer and
+    the pass count (a scalar)."""
+    kp = bestd.shape[1]
+    bc = d.shape[1]
+    surv = d < thr
+    rest = jnp.where(surv, d, jnp.inf)
+    # survivor counts as fp32 sums (exact below 2^24); the loop runs the
+    # fullest row's count, at most k
+    n = jnp.minimum(jnp.max(jnp.sum(jnp.where(surv, 1.0, 0.0), axis=1)),
+                    float(k)).astype(jnp.int32)
+    pos = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
     slot = jax.lax.broadcasted_iota(jnp.int32, bestd.shape, 1)
 
-    def extract(t, carry):
-        rest, outd, outi = carry
+    def insert(_, carry):
+        rest, bd, bi = carry
         m = jnp.min(rest, axis=1, keepdims=True)
-        first = jnp.min(jnp.where(rest == m, pos, width), axis=1,
+        first = jnp.min(jnp.where(rest == m, pos, bc), axis=1,
                         keepdims=True)
-        hit = pos == first
-        mi = jnp.max(jnp.where(hit, alli, -1), axis=1, keepdims=True)
-        outd = jnp.where(slot == t, m, outd)
-        outi = jnp.where(slot == t, mi, outi)
-        return jnp.where(hit, jnp.inf, rest), outd, outi
+        at = jnp.min(jnp.where(bd > m, slot, kp), axis=1, keepdims=True)
+        bd = jnp.where(slot < at, bd,
+                       jnp.where(slot == at, m, pltpu.roll(bd, 1, 1)))
+        bi = jnp.where(slot < at, bi,
+                       jnp.where(slot == at, idx0 + first,
+                                 pltpu.roll(bi, 1, 1)))
+        return jnp.where(pos == first, jnp.inf, rest), bd, bi
 
-    init = (alld, jnp.full_like(bestd, jnp.inf), jnp.full_like(besti, -1))
-    _, outd, outi = jax.lax.fori_loop(0, k, extract, init)
-    return outd, outi
+    _, bestd, besti = jax.lax.fori_loop(0, n, insert, (rest, bestd, besti))
+    return bestd, besti, n
 
 
 def _init_buffer(bestd_ref, besti_ref):
@@ -127,8 +159,9 @@ def _kernel(q_ref, p_ref, bestd_ref, besti_ref, *, bn: int, k: int,
     idx = (j * bn + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1))
     # padding points must never displace real neighbors
     d = jnp.where(idx < n_real, d, jnp.inf)
-    bestd_ref[...], besti_ref[...] = _merge_topk(
-        bestd_ref[...], besti_ref[...], d, idx, k)
+    bestd = bestd_ref[...]
+    bestd_ref[...], besti_ref[...], _ = _insert_survivors(
+        bestd, besti_ref[...], d, j * bn, _kth(bestd, k), k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bm", "bn", "interpret"))
@@ -173,22 +206,30 @@ def topk_l2_pallas(q, p, k: int, *, bm: int = 128, bn: int = 512,
 # ---------------------------------------------------------------------------
 # Row-masked, per-query-candidate variant (hybrid-engine leaf scan)
 # ---------------------------------------------------------------------------
-def _masked_kernel(q_ref, p_ref, v_ref, *refs, bc: int, k: int,
+def _masked_kernel(q_ref, p_ref, v_ref, kth0_ref, *refs, bc: int, k: int,
                    with_lb: bool):
     lb_ref = refs[0] if with_lb else None
-    bestd_ref, besti_ref = refs[-2:]
+    bestd_ref, besti_ref, work_ref = refs[-3:]
     j = pl.program_id(1)
     _init_buffer(bestd_ref, besti_ref)
+
+    @pl.when(j == 0)
+    def _init_work():
+        work_ref[...] = jnp.zeros_like(work_ref)
+
+    # the merged top-k can only take a candidate below both the caller's
+    # carry kth (lane 0 of the seed block) and the running buffer's kth
+    thr = jnp.minimum(kth0_ref[:, 0:1], _kth(bestd_ref[...], k))
     live = v_ref[...] != 0                      # (BG, BC)
     if with_lb:
         # tile early-out: a valid candidate whose squared ball bound is
-        # below its query's running kth distance is the only thing that
-        # can change the buffer; blocks with none of those are skipped
-        # wholesale (module docstring: ties never displace the stable
-        # running buffer, so >= is safe). An all-inf buffer (kth = +inf)
-        # never refutes a valid candidate, so the first tiles always
-        # merge.
-        live = live & (lb_ref[...] < _kth(bestd_ref[...], k))
+        # below the threshold is the only thing that can change the
+        # merged top-k; blocks with none of those are skipped wholesale
+        # (module docstring: ties never displace the stable running
+        # buffer or carry, so >= is safe). With no seed, an all-inf
+        # buffer (kth = +inf) never refutes a valid candidate, so the
+        # first tiles always merge.
+        live = live & (lb_ref[...] < thr)
 
     # a block with no live candidate is skipped with or without bounds,
     # so both variants run the merge under the same predicate shape
@@ -199,12 +240,16 @@ def _masked_kernel(q_ref, p_ref, v_ref, *refs, bc: int, k: int,
         qq = jnp.sum(q * q, axis=1, keepdims=True)  # (BG, 1)
         pp = jnp.sum(p * p, axis=2)                 # (BG, BC)
         d = jnp.maximum(qq + pp - 2.0 * _rowwise_dot(q, p), 0.0)
-        idx = (j * bc + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1))
         # masked rows (bucket padding, filtered-out predicate rows) never
         # win
         d = jnp.where(v_ref[...] != 0, d, jnp.inf)
-        bestd_ref[...], besti_ref[...] = _merge_topk(
-            bestd_ref[...], besti_ref[...], d, idx, k)
+        bestd_ref[...], besti_ref[...], n = _insert_survivors(
+            bestd_ref[...], besti_ref[...], d, j * bc, thr, k)
+        # work counters, replicated down the block's rows: lane 0 the
+        # grid steps that computed distances, lane 1 their merge passes
+        lane = jax.lax.broadcasted_iota(jnp.int32, work_ref.shape, 1)
+        work_ref[...] += jnp.where(lane == 0, 1,
+                                   jnp.where(lane == 1, n, 0))
 
 
 def _tpu_bc(bg: int, dp: int, itemsize: int) -> int:
@@ -219,13 +264,23 @@ def _tpu_bc(bg: int, dp: int, itemsize: int) -> int:
 @functools.partial(jax.jit, static_argnames=("k", "bg", "bc", "interpret"))
 def topk_l2_masked_pallas(q, p, valid, k: int, *, bg: int = None,
                           bc: int = None, interpret: bool = False,
-                          lb2=None):
-    """q: (G, D), p: (G, C, D), valid: (G, C) -> (dists (G, k), idx (G, k)).
+                          lb2=None, kth0=None):
+    """q: (G, D), p: (G, C, D), valid: (G, C) -> (dists (G, k), idx (G, k),
+    work (R, 2)).
 
     Row g of ``p`` is query g's own candidate tile; ``valid`` entries of 0
     (bucket padding / filtered rows) are excluded. Returned squared
     distances are ascending; exhausted slots come back as (inf, -1) and
-    indices point into [0, C).
+    indices point into [0, C). ``work`` holds, per group of ``bg`` rows,
+    the grid steps that computed distances and the merge passes they
+    ran (the ``engine.topk_blocks`` / ``engine.topk_merge_passes``
+    counters).
+
+    ``kth0`` (optional, (G,)): each query's kth SQUARED distance of the
+    carry the result will be merged into, carry first, with a stable
+    top-k (module docstring). Candidates at or above it are left out;
+    the merged result is the same as without it. None (or +inf) gives
+    the exact top-k of the candidates.
 
     ``lb2`` (optional, (G, C)): per-candidate SQUARED lower bounds for
     the tile early-out (module docstring) — grid steps whose valid
@@ -256,6 +311,10 @@ def topk_l2_masked_pallas(q, p, valid, k: int, *, bg: int = None,
     q2 = _pad(_pad(q.astype(jnp.float32), dpad, 1), bg, 0)
     p2 = _pad(_pad(_pad(p.astype(jnp.float32), dpad, 2), bc, 1), bg, 0)
     v2 = _pad(_pad(valid.astype(jnp.int32), bc, 1), bg, 0)
+    # the seed, one lane-wide row per query (padding rows are all-invalid)
+    seed = (jnp.full((g,), jnp.inf, jnp.float32) if kth0 is None
+            else kth0.astype(jnp.float32))
+    k2 = _pad(jnp.broadcast_to(seed[:, None], (g, 128)), bg, 0)
     gp = q2.shape[0]
     cp = p2.shape[1]
     grid = (gp // bg, cp // bc)
@@ -263,14 +322,15 @@ def topk_l2_masked_pallas(q, p, valid, k: int, *, bg: int = None,
         pl.BlockSpec((bg, dp), lambda i, j: (i, 0)),
         pl.BlockSpec((bg, bc, dp), lambda i, j: (i, j, 0)),
         pl.BlockSpec((bg, bc), lambda i, j: (i, j)),
+        pl.BlockSpec((bg, 128), lambda i, j: (i, 0)),
     ]
-    operands = [q2, p2, v2]
+    operands = [q2, p2, v2, k2]
     if lb2 is not None:
         # pad columns carry +inf bounds (can never force a merge)
         operands.append(_pad(_pad(lb2.astype(jnp.float32), bc, 1,
                                   value=jnp.inf), bg, 0))
         in_specs.append(pl.BlockSpec((bg, bc), lambda i, j: (i, j)))
-    bestd, besti = pl.pallas_call(
+    bestd, besti, work = pl.pallas_call(
         functools.partial(_masked_kernel, bc=bc, k=kk,
                           with_lb=lb2 is not None),
         grid=grid,
@@ -278,10 +338,12 @@ def topk_l2_masked_pallas(q, p, valid, k: int, *, bg: int = None,
         out_specs=[
             pl.BlockSpec((bg, kp), lambda i, j: (i, 0)),
             pl.BlockSpec((bg, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((bg, 128), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((gp, kp), jnp.float32),
             jax.ShapeDtypeStruct((gp, kp), jnp.int32),
+            jax.ShapeDtypeStruct((gp, 128), jnp.int32),
         ],
         interpret=interpret,
         name="topk_l2_masked_pallas",
@@ -292,7 +354,7 @@ def topk_l2_masked_pallas(q, p, valid, k: int, *, bg: int = None,
         bestd = jnp.pad(bestd, ((0, 0), (0, k - kk)),
                         constant_values=jnp.inf)
         besti = jnp.pad(besti, ((0, 0), (0, k - kk)), constant_values=-1)
-    return bestd, besti
+    return bestd, besti, work.reshape(gp // bg, bg, 128)[:, 0, :2]
 
 
 # ---------------------------------------------------------------------------

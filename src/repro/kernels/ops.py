@@ -69,20 +69,26 @@ def topk_l2(q, p, k: int, interpret: bool = False):
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def topk_l2_masked(q, p, valid, k: int, interpret: bool = False,
-                   lb2=None):
+                   lb2=None, kth0=None):
     """Per-query candidate tiles + validity mask (hybrid-engine leaf scan).
     q: (G, D), p: (G, C, D), valid: (G, C). ``lb2`` (optional, (G, C)):
     per-candidate squared ball lower bounds — enables the Pallas tile
     early-out (skip a grid step's distance + merge when no valid
-    candidate's bound beats the running kth); never changes results. The
-    pure-jnp reference path computes everything regardless and ignores
-    it."""
+    candidate's bound beats the threshold). ``kth0`` (optional, (G,)):
+    the kth squared distance of the carry the caller merges the result
+    into, carry first, with a stable top-k — candidates at or above it
+    are left out. Neither changes the merged result. Returns (d2, idx,
+    work): ``work`` (2,) int32 is the kernel's grid steps that computed
+    distances and the merge passes they ran. The pure-jnp reference
+    path computes everything regardless, ignores both hints and reports
+    no kernel work."""
     if use_pallas() or interpret:
         from repro.kernels.fused_topk import topk_l2_masked_pallas
-        return topk_l2_masked_pallas(q, p, valid, k,
-                                     interpret=not use_pallas(),
-                                     lb2=lb2)
-    return ref.topk_l2_masked(q, p, valid, k)
+        d2, idx, work = topk_l2_masked_pallas(
+            q, p, valid, k, interpret=not use_pallas(), lb2=lb2, kth0=kth0)
+        return d2, idx, jnp.sum(work, axis=0)
+    d2, idx = ref.topk_l2_masked(q, p, valid, k)
+    return d2, idx, jnp.zeros(2, jnp.int32)
 
 
 def _ceil_pow2(x: int) -> int:

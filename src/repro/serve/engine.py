@@ -960,6 +960,8 @@ class RetrievalServer:
         rec.count("engine.queries", stats.queries)
         rec.count("engine.rows_scanned", stats.rows_scanned)
         rec.count("engine.knn_rounds", stats.knn_rounds)
+        rec.count("engine.topk_blocks", stats.topk_blocks)
+        rec.count("engine.topk_merge_passes", stats.topk_merge_passes)
         rec.count("engine.knn_buckets", stats.knn_buckets)
         rec.count("engine.predicate_buckets", stats.predicate_buckets)
 
@@ -996,7 +998,8 @@ class RetrievalServer:
         ``serve.rounded_off`` (requests a partial group's power-of-two
         round-down left queued), and the chunks' summed ``EngineStats``
         (``engine.queries``, ``engine.rows_scanned``,
-        ``engine.knn_rounds``, ``engine.knn_buckets``,
+        ``engine.knn_rounds``, ``engine.topk_blocks``,
+        ``engine.topk_merge_passes``, ``engine.knn_buckets``,
         ``engine.predicate_buckets``)."""
         by_sig = {}
         for sig, ls in self._e2e.items():

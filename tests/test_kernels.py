@@ -51,7 +51,8 @@ def test_topk_masked_sweep(g, c, d, k, density):
     q = _arr((g, d), np.float32)
     p = _arr((g, c, d), np.float32)
     v = jnp.asarray(RNG.random((g, c)) < density)
-    gd, gi = topk_l2_masked_pallas(q, p, v, k, bg=4, bc=64, interpret=True)
+    gd, gi, _ = topk_l2_masked_pallas(q, p, v, k, bg=4, bc=64,
+                                      interpret=True)
     wd, wi = ref.topk_l2_masked(q, p, v, k)
     gd, gi, wd, wi = map(np.asarray, (gd, gi, wd, wi))
     # identical validity pattern, same distances, same index sets
@@ -77,7 +78,7 @@ def test_topk_masked_all_masked_tiles():
     v = np.ones((g, c), bool)
     v[0] = False                      # fully masked query
     v[2, 50:] = False                 # half-masked query
-    gd, gi = topk_l2_masked_pallas(q, p, jnp.asarray(v), k,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, jnp.asarray(v), k,
                                    bg=2, bc=32, interpret=True)
     gd, gi = np.asarray(gd), np.asarray(gi)
     assert (gi[0] == -1).all() and np.isinf(gd[0]).all()
@@ -97,7 +98,7 @@ def test_topk_masked_k_exceeds_surviving_rows():
     v[0, :7] = True
     v[1, :1] = True                   # single survivor
     v[2, :] = True                    # k < c here? no: k=25 < c=40
-    gd, gi = topk_l2_masked_pallas(q, p, jnp.asarray(v), k,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, jnp.asarray(v), k,
                                    bg=2, bc=16, interpret=True)
     gd, gi = np.asarray(gd), np.asarray(gi)
     assert (gi[0] >= 0).sum() == 7 and np.isinf(gd[0][7:]).all()
@@ -112,7 +113,7 @@ def test_topk_masked_k_exceeds_candidate_width():
     q = _arr((g, d), np.float32)
     p = _arr((g, c, d), np.float32)
     v = jnp.asarray(np.ones((g, c), bool))
-    gd, gi = topk_l2_masked_pallas(q, p, v, k, interpret=True)
+    gd, gi, _ = topk_l2_masked_pallas(q, p, v, k, interpret=True)
     gd, gi = np.asarray(gd), np.asarray(gi)
     assert gd.shape == (g, k) and gi.shape == (g, k)
     assert (gi[:, :c] >= 0).all() and (gi[:, c:] == -1).all()
@@ -127,7 +128,7 @@ def test_topk_masked_duplicate_distances():
     base = np.asarray(_arr((g, c // 2, d), np.float32))
     p = jnp.asarray(np.concatenate([base, base], axis=1))  # every point x2
     v = jnp.asarray(np.ones((g, c), bool))
-    gd, gi = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=16,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=16,
                                    interpret=True)
     wd, wi = ref.topk_l2_masked(q, p, v, k)
     gd, gi, wd, wi = map(np.asarray, (gd, gi, wd, wi))
@@ -150,7 +151,7 @@ def test_topk_masked_ragged_tile_counts(c, bc):
     q = _arr((g, d), np.float32)
     p = _arr((g, c, d), np.float32)
     v = jnp.asarray(RNG.random((g, c)) < 0.6)
-    gd, gi = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=bc,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=bc,
                                    interpret=True)
     wd, wi = ref.topk_l2_masked(q, p, v, k)
     gd, gi, wd, wi = map(np.asarray, (gd, gi, wd, wi))
@@ -180,7 +181,7 @@ def test_topk_masked_early_out_identical(bound):
     the result; +inf on masked columns composes with the skip."""
     q, p, v = _eo_case()
     k = 6
-    base_d, base_i = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=32,
+    base_d, base_i, _ = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=32,
                                            interpret=True)
     dtrue = jnp.maximum(((p - q[:, None, :]) ** 2).sum(-1), 0.0)
     if bound == "zero":
@@ -191,7 +192,7 @@ def test_topk_masked_early_out_identical(bound):
         lb2 = 0.5 * dtrue
     else:
         lb2 = jnp.where(v, 0.0, jnp.inf)
-    gd, gi = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=32,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, v, k, bg=2, bc=32,
                                    interpret=True, lb2=lb2)
     assert np.array_equal(np.asarray(base_i), np.asarray(gi)), bound
     np.testing.assert_array_equal(np.asarray(base_d), np.asarray(gd))
@@ -201,7 +202,7 @@ def test_topk_masked_early_out_all_masked():
     """All-masked input with bounds: still (inf, -1) everywhere."""
     q, p, v = _eo_case()
     lb2 = jnp.zeros(v.shape, jnp.float32)
-    gd, gi = topk_l2_masked_pallas(q, p, jnp.zeros_like(v), 4, bg=2,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, jnp.zeros_like(v), 4, bg=2,
                                    bc=32, interpret=True, lb2=lb2)
     assert (np.asarray(gi) == -1).all()
     assert np.isinf(np.asarray(gd)).all()
@@ -221,7 +222,7 @@ def test_topk_masked_early_out_skippable_blocks():
     lb2 = jnp.concatenate([jnp.zeros((len(v_np), 32), jnp.float32),
                            jnp.full((len(v_np), 64), jnp.inf,
                                     jnp.float32)], axis=1)
-    gd, gi = topk_l2_masked_pallas(q, p, v2, k, bg=2, bc=32,
+    gd, gi, _ = topk_l2_masked_pallas(q, p, v2, k, bg=2, bc=32,
                                    interpret=True, lb2=lb2)
     wd, wi = ref.topk_l2_masked(q, p, v2, k)
     fin = np.isfinite(np.asarray(wd))
@@ -385,7 +386,7 @@ def _int_case(g, c, d, lo=-3, hi=4, dup=1):
 
 
 def _assert_merge_exact(got, want):
-    gd, gi = map(np.asarray, got)
+    gd, gi = map(np.asarray, got[:2])
     wd, wi = map(np.asarray, want)
     np.testing.assert_array_equal(gd, wd)
     np.testing.assert_array_equal(gi, wi)
@@ -444,6 +445,78 @@ def test_merge_k100(c, bc):
     _assert_merge_exact(
         topk_l2_masked_pallas(q, p, v, 100, bg=4, bc=bc, interpret=True),
         ref.topk_l2_masked(q, p, v, 100))
+
+
+def _stable_merge(cd, ci, d, i, k):
+    """The callers' merge: carry first, one stable top_k."""
+    nd, pick = jax.lax.top_k(-jnp.concatenate([cd, d], 1), k)
+    return -nd, jnp.take_along_axis(jnp.concatenate([ci, i], 1), pick, 1)
+
+
+# (k, d, c, bc): k and d of the two benchmark configurations; the last
+# case's candidate count is not a multiple of its block width
+SEEDED = [(10, 128, 256, 64), (100, 768, 512, 128), (100, 128, 460, 96)]
+
+
+@pytest.mark.parametrize("with_lb", [False, True])
+@pytest.mark.parametrize("k,d,c,bc", SEEDED)
+def test_merge_seeded_carry_matches_ref(k, d, c, bc, with_lb):
+    """A call seeded with its carry's kth, merged carry first with a
+    stable top_k, equals the reference over carry and block together.
+    The block repeats the carry's candidates (distances exactly at the
+    seed) and each of its own twice (ties inside one block); with the
+    exact distances as bounds, whole blocks are skipped on them."""
+    g, h = 6, c // 2
+    q, half = _int_case(g, h, d, lo=0, hi=2)
+    p = jnp.concatenate([half, half[:, :h // 2], half[:, :h - h // 2]],
+                        axis=1)
+    v = jnp.asarray(RNG.random((g, 2 * h)) < 0.9)
+    cd, ci = ref.topk_l2_masked(q, p[:, :h], v[:, :h], k)
+    lb2 = (((p[:, h:] - q[:, None, :]) ** 2).sum(-1) if with_lb else None)
+    sd, si, work = topk_l2_masked_pallas(q, p[:, h:], v[:, h:], k, bg=2,
+                                         bc=bc, interpret=True, lb2=lb2,
+                                         kth0=cd[:, -1])
+    si = jnp.where(si >= 0, si + h, -1)
+    _assert_merge_exact(_stable_merge(cd, ci, sd, si, k),
+                        ref.topk_l2_masked(q, p, v, k))
+    # nothing at or above the seed is inserted
+    assert (np.asarray(sd) < np.asarray(cd[:, -1:])).sum() == \
+        np.isfinite(np.asarray(sd)).sum()
+    assert int(np.asarray(work)[:, 1].sum()) <= k * int(
+        np.asarray(work)[:, 0].sum())
+
+
+@pytest.mark.parametrize("case", ["inf", "below", "first_block"])
+@pytest.mark.parametrize("k,d,c,bc", SEEDED)
+def test_merge_pass_count(k, d, c, bc, case):
+    """The merge runs one pass per candidate that can still enter the
+    top-k: a +inf seed is the unseeded call bit for bit, a seed below
+    every distance gives no pass, and a first block into an empty
+    buffer gives min(k, survivors) passes, survivors the fullest row's
+    valid candidates."""
+    g = 4
+    q, p = _int_case(g, c, d)
+    v = jnp.asarray(RNG.random((g, c)) < 0.7)
+    base = topk_l2_masked_pallas(q, p, v, k, bg=g, bc=bc, interpret=True)
+    blocks = -(-c // bc)
+    if case == "inf":
+        got = topk_l2_masked_pallas(q, p, v, k, bg=g, bc=bc,
+                                    interpret=True,
+                                    kth0=jnp.full((g,), jnp.inf))
+        for a, b in zip(got, base):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    elif case == "below":
+        gd, gi, work = topk_l2_masked_pallas(
+            q, p, v, k, bg=g, bc=bc, interpret=True,
+            kth0=jnp.full((g,), -1.0))
+        assert np.isinf(np.asarray(gd)).all() and (np.asarray(gi) == -1).all()
+        assert np.asarray(work).tolist() == [[blocks, 0]]
+    else:
+        _, _, work = topk_l2_masked_pallas(q, p[:, :bc], v[:, :bc], k,
+                                           bg=g, bc=bc, interpret=True)
+        fullest = int(np.asarray(v[:, :bc]).sum(1).max())
+        assert np.asarray(work).tolist() == [[1, min(k, fullest)]]
+    assert int(np.asarray(base[2])[0, 1]) <= k * blocks
 
 
 @pytest.mark.parametrize("k", [2, 10, 100])

@@ -24,7 +24,7 @@ ENGINE_SPANS = ("engine.masks", "engine.masks_fence",
                 "engine.stragglers", "engine.straggler_fence",
                 "engine.walk", "plan.feedback")
 ENGINE_COUNTERS = ("queries", "rows_scanned", "knn_rounds", "knn_buckets",
-                   "predicate_buckets")
+                   "predicate_buckets", "topk_blocks", "topk_merge_passes")
 
 
 # ---------------------------------------------------------------------------
@@ -219,6 +219,17 @@ def test_engine_counters_equal_the_plans_engine_stats(platform,
     assert len(seen) == srv.stats()["batches"]
     for name in ENGINE_COUNTERS:
         assert c["engine." + name] == sum(getattr(s, name) for s in seen)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_topk_counters_bound_the_merge(platform, depth):
+    """The top-k kernel's grid steps and merge passes reach the trace:
+    at most k passes a step (k=60), and far fewer once the straggler
+    rounds are seeded with their carry's kth."""
+    srv, _ = _serve(platform, depth, _requests())
+    c = srv.stats()["trace"]["counters"]
+    blocks, passes = c["engine.topk_blocks"], c["engine.topk_merge_passes"]
+    assert 0 < passes <= 60 * blocks
 
 
 def test_window_and_backpressure_reasons(platform):

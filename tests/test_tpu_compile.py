@@ -60,17 +60,19 @@ def _compile(fn, sharding, *shapes):
 @pytest.mark.parametrize("d", [128, 768])
 @pytest.mark.parametrize("k", [10, 100])
 @pytest.mark.parametrize("with_lb", [False, True])
-def test_topk_l2_masked_compiles(one_chip, d, k, with_lb):
+@pytest.mark.parametrize("with_kth0", [False, True])
+def test_topk_l2_masked_compiles(one_chip, d, k, with_lb, with_kth0):
     shapes = [((G, d), jnp.float32), ((G, C, d), jnp.float32),
               ((G, C), jnp.bool_)]
     if with_lb:
         shapes.append(((G, C), jnp.float32))
+    if with_kth0:
+        shapes.append(((G,), jnp.float32))
 
-        def fn(q, p, v, lb2):
-            return topk_l2_masked_pallas(q, p, v, k, lb2=lb2)
-    else:
-        def fn(q, p, v):
-            return topk_l2_masked_pallas(q, p, v, k)
+    def fn(q, p, v, *hints):
+        lb2 = hints[0] if with_lb else None
+        kth0 = hints[-1] if with_kth0 else None
+        return topk_l2_masked_pallas(q, p, v, k, lb2=lb2, kth0=kth0)
     _compile(fn, one_chip, *shapes)
 
 
