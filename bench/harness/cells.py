@@ -4,16 +4,20 @@ A cell names a configuration and a traffic mix; each is a file of its own,
 found by name: ``<bench>/configs/<config>.json`` (the file that
 ``BENCHMARK.json``'s configuration entry lists) and
 ``<bench>/traffic/<mix>.json``. Each per-layer metric is read by
-``<bench>/metrics/<metric>.py``. A later cell, configuration, mix or
-metric is added as new files and entries, with no edit to these.
+``<bench>/metrics/<metric>.py``; the generator, column kinds and
+predicate kinds a configuration or mix names are files of their own too
+(``kinds.py``), found under the same ``<bench>``. A later cell,
+configuration, mix, metric, generator, column kind or predicate kind is
+added as new files and entries, with no edit to these.
 """
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 from dataclasses import dataclass
 from typing import Callable, List
+
+from harness.kinds import Kinds
 
 
 @dataclass
@@ -31,6 +35,7 @@ class Cell:
     mix: dict
     end_to_end: List[Metric]
     per_layer: List[Metric]
+    kinds: Kinds
 
 
 def _load_json(path: str) -> dict:
@@ -39,14 +44,7 @@ def _load_json(path: str) -> dict:
 
 
 def _reader(bench_dir: str, name: str) -> Callable:
-    path = os.path.join(bench_dir, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    if spec is None or not os.path.isfile(path):
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return Kinds(bench_dir).module("metrics", name).read
 
 
 def resolve(benchmark_json: str, cell: str) -> Cell:
@@ -64,8 +62,10 @@ def resolve(benchmark_json: str, cell: str) -> Cell:
     config = _load_json(os.path.join(root, configs[w["config"]]["file"]))
     mix = _load_json(os.path.join(bench_dir, "traffic",
                                   f"{w['traffic']}.json"))
+    kinds = Kinds(bench_dir)
     e2e = [Metric(m["name"], m["unit"]) for m in spec["end_to_end"]]
-    layer = [Metric(m["name"], m["unit"], _reader(bench_dir, m["name"]))
+    layer = [Metric(m["name"], m["unit"],
+                    kinds.module("metrics", m["name"]).read)
              for m in spec["per_layer"]]
     return Cell(name=cell, chips=int(w["chips"]), config=config, mix=mix,
-                end_to_end=e2e, per_layer=layer)
+                end_to_end=e2e, per_layer=layer, kinds=kinds)

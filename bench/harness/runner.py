@@ -10,6 +10,7 @@ reuses its stages to find a cell's knee.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import json
 import os
@@ -23,7 +24,7 @@ import numpy as np
 
 from harness import data as datamod
 from harness import drive as drivemod
-from harness import reference, trace as tracemod, traffic
+from harness import predicates, reference, trace as tracemod, traffic
 from harness.cells import Cell
 
 EXTRA = 32          # reference candidates beyond k, for the float64 recheck
@@ -62,22 +63,25 @@ class Setup:
     compiles: drivemod.Compiles
 
 
-def request(spec: traffic.Spec):
-    from repro.core import query as Q
+def request(spec: traffic.Spec, ds: datamod.Dataset):
+    """The program's request for ``spec``: its filter tree built by the
+    predicate kinds."""
     from repro.serve.engine import RetrievalRequest
-    pred = None
-    if spec.filter is not None:
-        pred = Q.NR(spec.filter[0], spec.filter[1], spec.filter[2])
+    pred = None if spec.filter is None else predicates.build(spec.filter, ds)
     return RetrievalRequest(tokens=np.asarray([spec.pool_idx, 0], np.int32),
-                            attr="v", k=spec.k, predicate=pred)
+                            attr=reference.VECTOR, k=spec.k, predicate=pred)
 
 
 def server(st: Setup):
+    """A fresh server over the set-up platform; a cell on more than one
+    chip is served sharded over that many."""
     from repro.serve.engine import RetrievalServer
     s = st.cell.mix["server"]
+    chips = int(st.cell.chips)
+    shards = {"shards": chips} if chips > 1 else {}
     return RetrievalServer(st.platform, PoolEmbedder(st.ds.pool),
                            batch_size=int(s["batch_size"]),
-                           max_delay_ms=float(s["max_delay_ms"]))
+                           max_delay_ms=float(s["max_delay_ms"]), **shards)
 
 
 def memory(dev) -> Dict[str, int]:
@@ -86,24 +90,35 @@ def memory(dev) -> Dict[str, int]:
             if k in st}
 
 
-def limits() -> Dict[str, float]:
+def _limits_file() -> dict:
     with open(LIMITS) as f:
-        return {k: float(v["limit"]) for k, v in json.load(f).items()
-                if not k.startswith("_")}
+        return json.load(f)
 
 
-def warm(srv, specs: List[traffic.Spec], batch_size: int) -> int:
+def limits() -> Dict[str, float]:
+    """The compared numbers' limits."""
+    return {k: float(v["limit"]) for k, v in _limits_file().items()
+            if isinstance(v, dict) and "limit" in v}
+
+
+def band() -> float:
+    """The share of a V.R radius within which rounding decides a row."""
+    return float(_limits_file()["vr_band"]["band"])
+
+
+def warm(srv, specs: List[traffic.Spec], batch_size: int,
+         make: Callable) -> int:
     """Run each plan signature of ``specs`` once at every micro-batch
     size the server can form; returns the requests used."""
     by_sig: Dict[str, List[traffic.Spec]] = {}
     for s in specs:
-        by_sig.setdefault(srv.signature(request(s)), []).append(s)
+        by_sig.setdefault(srv.signature(make(s)), []).append(s)
     used = 0
     for group in by_sig.values():
         for size in traffic.pow2_sizes(batch_size):
             batch = [group[(used + j) % len(group)] for j in range(size)]
             used += size
-            futs = [srv.submit(request(s)) for s in batch]
+            futs = [srv.submit(make(s)) for s in batch]
             srv.flush()
             for f in futs:
                 f.result()
@@ -119,10 +134,11 @@ def setup(cell: Cell, seed: int, skip: int, device,
     compiles = drivemod.Compiles()
     cfg, mix = cell.config, cell.mix
     t = time.monotonic()
-    ds = datamod.make(cfg)
-    table = MMOTable(cfg["name"]).add_vector("v", ds.vectors)
+    ds = datamod.make(cfg, cell.kinds)
+    table = MMOTable(cfg["name"]).add_vector(reference.VECTOR, ds.vectors)
     for name, col in ds.numeric.items():
-        table.add_numeric(name, col)
+        kind = cfg["numeric"][name]["kind"]
+        ds.kinds.module("columns", kind).add(table, name, col)
     log(f"data: rows={len(ds.vectors)} dim={ds.vectors.shape[1]} "
         f"pool={len(ds.pool)} gen_s={time.monotonic() - t:.3f}")
     platform = MQRLD(table)         # the program's default build
@@ -136,12 +152,13 @@ def setup(cell: Cell, seed: int, skip: int, device,
     # every signature at every micro-batch size, then an unmeasured
     # stretch of the cell's own traffic, so that QBS seeds settle
     warm_s = float(mix["warmup_s"])
-    specs = traffic.schedule(mix, ds.numeric, len(ds.pool), seed, warm_s,
-                             skip=skip)
+    specs = traffic.schedule(mix, ds, seed, warm_s, skip=skip)
     t = time.monotonic()
-    n_sizes = warm(server(st), specs, int(mix["server"]["batch_size"]))
+    srv = server(st)
+    make = functools.partial(request, ds=ds)
+    n_sizes = warm(srv, specs, int(mix["server"]["batch_size"]), make)
     t_sizes = time.monotonic() - t
-    ww = drivemod.drive(server(st), specs, request, warm_s)
+    ww = drivemod.drive(server(st), specs, make, warm_s)
     if ww.error:
         raise RuntimeError(f"warm-up stretch failed: {ww.error}")
     mem = memory(device)
@@ -150,6 +167,7 @@ def setup(cell: Cell, seed: int, skip: int, device,
         f"stretch_served={ww.stats['served']} "
         f"lowered={compiles.lowered.get('setup', 0)} "
         f"cache_hits={compiles.hits.get('setup', 0)} "
+        f"shards={srv.session.engine().shards or 1} "
         f"bytes_in_use_after_upload_and_warmup="
         f"{mem.get('bytes_in_use', 'n/a')}")
     return st
@@ -176,7 +194,8 @@ def window(st: Setup, specs: List[traffic.Spec], seconds: float, *,
             jax.profiler.stop_trace()
         closed.append(True)
 
-    win = drivemod.drive(srv, specs, request, seconds,
+    make = functools.partial(request, ds=st.ds)
+    win = drivemod.drive(srv, specs, make, seconds,
                          spans=trace_dir is not None, on_close=on_close)
     on_close()
     st.compiles.phase = "after"
@@ -212,31 +231,31 @@ def summary(st: Setup, win: drivemod.Window) -> Dict:
 
 def check(ds: datamod.Dataset, specs, served) -> Dict[str, float]:
     """The numbers that decide ``correct``, from the reference."""
-    ref = reference.Reference(ds.vectors, ds.numeric)
-    cands = ref.topk(ds.pool, specs, extra=EXTRA)
+    b = band()
+    ref = reference.Reference(ds)
+    cands = ref.candidates(specs, extra=EXTRA, band=b)
     del ref
-    return reference.compare(specs, served, cands, ds.vectors, ds.numeric,
-                             ds.pool)
+    return reference.compare(specs, served, cands, ds, b)
 
 
-def control(ds: datamod.Dataset, specs) -> Dict[str, float]:
-    """The control's numbers: the reference in bfloat16 put in the
-    program's place, its top-k answers compared like served ones."""
-    ref = reference.Reference(ds.vectors, ds.numeric)
-    cands = ref.topk(ds.pool, specs, extra=EXTRA)
-    low = ref.topk(ds.pool, specs, extra=0, precision="bf16")
+def control(ds: datamod.Dataset, specs, b: float) -> Dict[str, float]:
+    """The control's numbers at the V.R band ``b``: the reference in
+    bfloat16 put in the program's place, its top-k answers compared like
+    served ones."""
+    ref = reference.Reference(ds)
+    cands = ref.candidates(specs, extra=EXTRA, band=b)
+    low = ref.topk(specs, extra=0, precision="bf16")
     del ref
-    return reference.compare(specs, low, cands, ds.vectors, ds.numeric,
-                             ds.pool)
+    return reference.compare(specs, low, cands, ds, b)
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool,
              t_start: float, device,
              log: Callable[[str], None] = print) -> Dict:
     import jax
-    st = setup(cell, seed, traffic.count(cell.mix, seconds), device, log)
-    specs = traffic.schedule(cell.mix, st.ds.numeric, len(st.ds.pool), seed,
-                             seconds)
+    st = setup(cell, seed, traffic.pool_needed(cell.mix, seconds), device,
+               log)
+    specs = traffic.schedule(cell.mix, st.ds, seed, seconds)
     tdir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     setup_s = time.monotonic() - t_start
     win = window(st, specs, seconds, trace_dir=tdir)

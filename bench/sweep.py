@@ -72,7 +72,8 @@ def full_batches(st, specs, reps: int = 3) -> dict:
     size = int(st.cell.mix["server"]["batch_size"])
     by_sig: dict = {}
     for s in specs:
-        by_sig.setdefault(srv.signature(runner.request(s)), []).append(s)
+        sig = srv.signature(runner.request(s, st.ds))
+        by_sig.setdefault(sig, []).append(s)
     out = {}
     for sig, group in by_sig.items():
         times = []
@@ -80,7 +81,7 @@ def full_batches(st, specs, reps: int = 3) -> dict:
             batch = group[r * size:(r + 1) * size]
             if len(batch) < size:
                 break
-            reqs = [runner.request(s) for s in batch]
+            reqs = [runner.request(s, st.ds) for s in batch]
             t = time.monotonic()    # a full group runs inside submit()
             futs = [srv.submit(q) for q in reqs]
             srv.flush()
@@ -102,7 +103,8 @@ def main() -> None:
     ap.add_argument("--data-seed", type=int, default=None)
     ap.add_argument("--dump", default=None)
     args = ap.parse_args()
-    from harness import cells, drive, runner, trace as tracemod, traffic
+    from harness import (cells, drive, predicates, runner,
+                         trace as tracemod, traffic)
     cell = cells.resolve(os.path.join(run.ROOT, "BENCHMARK.json"),
                          args.workload)
     if args.data_seed is not None:
@@ -119,15 +121,15 @@ def main() -> None:
         else [args.seed]
     mixes = [dict(cell.mix, arrival=dict(cell.mix["arrival"], rate_per_s=r))
              for r in rates]
-    longest = max(traffic.count(m, args.seconds) for m in mixes)
+    longest = max(traffic.pool_needed(m, args.seconds) for m in mixes)
     st = runner.setup(cell, args.seed, longest, dev, run.log)
     run.log(f"setup_s={time.monotonic() - T_START:.3f}")
 
     # up to a thousand requests of the mix: three full batches per signature
+    per = len(st.ds.pool) // len(predicates.vectors_of(cell.mix))
     fb = full_batches(st, traffic.schedule(
-        cell.mix, st.ds.numeric, len(st.ds.pool), args.seed + 1,
-        min(1000, len(st.ds.pool))
-        / float(cell.mix["arrival"]["rate_per_s"])))
+        cell.mix, st.ds, args.seed + 1,
+        min(1000, per) / float(cell.mix["arrival"]["rate_per_s"])))
     allfb = sorted(t for ts in fb.values() for t in ts)
     fb_ms = drive.quantile(allfb, 0.5) * 1e3 if allfb else None
     print(json.dumps({"full_batch_ms": {k: [t * 1e3 for t in v]
@@ -144,8 +146,7 @@ def main() -> None:
     first = True
     for seed in seeds:
         for rate, mix in zip(rates, mixes):
-            specs = traffic.schedule(mix, st.ds.numeric, len(st.ds.pool),
-                                     seed, args.seconds)
+            specs = traffic.schedule(mix, st.ds, seed, args.seconds)
             st.cell = dataclasses.replace(st.cell, mix=dict(mix,
                                                             server=server))
             tdir = tempfile.mkdtemp(prefix="bench-sweep-") \

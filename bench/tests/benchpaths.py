@@ -27,24 +27,54 @@ TINY_MIX = {
     "warmup_s": 0.5,
 }
 
+# every predicate kind at a tiny size, over sift1m's two numeric columns:
+# V.R around a second held-out vector ``q2``, alone, Or'ed with an N.R
+# and And'ed with one
+TINY_HYBRID_CONFIG = dict(
+    TINY_CONFIG, name="tinyh", rows=3000,
+    numeric={"a": {"kind": "uniform", "lo": 0.0, "hi": 100.0},
+             "b": {"kind": "normal"}})
+TINY_HYBRID_ARCHETYPES = [
+    {"name": "vk10_vr", "share": 0.4, "k": 10,
+     "filter": {"vr": {"column": "v", "around": "q2",
+                       "pass_fraction": 0.01}}},
+    {"name": "vk10_vr_or_nr", "share": 0.3, "k": 10,
+     "filter": {"or": [
+         {"vr": {"column": "v", "around": "q2", "pass_fraction": 0.001}},
+         {"nr": {"column": "a", "pass_fraction": 0.01}}]}},
+    {"name": "vk10_vr_and_nr", "share": 0.3, "k": 10,
+     "filter": {"and": [
+         {"vr": {"column": "v", "around": "q2", "pass_fraction": 0.1}},
+         {"nr": {"column": "b", "pass_fraction": 0.1}}]}},
+]
 
-def add_cell(tmp: str) -> str:
-    """A copy of the benchmark in ``tmp`` with one more cell, added as new
-    files and new entries only; returns its ``BENCHMARK.json``."""
+
+def tiny_hybrid_mix(rate: float = 20.0) -> dict:
+    return {"arrival": {"process": "poisson", "rate_per_s": rate},
+            "vectors": ["q", "q2"], "archetypes": TINY_HYBRID_ARCHETYPES,
+            "server": {"batch_size": 4, "max_delay_ms": 30.0},
+            "warmup_s": 0.5}
+
+
+def add_cell(tmp: str, config: dict = None, mix: dict = None,
+             chips: int = 1) -> str:
+    """A copy of the benchmark in ``tmp`` with one more cell, ``tiny``
+    under ``tiny-mix`` on ``chips``, added as new files and new entries
+    only; returns its ``BENCHMARK.json``."""
     shutil.copytree(BENCH, os.path.join(tmp, "bench"),
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
     with open(os.path.join(tmp, "bench", "configs", "tiny.json"), "w") as f:
-        json.dump(TINY_CONFIG, f)
+        json.dump(dict(config or TINY_CONFIG, name="tiny"), f)
     with open(os.path.join(tmp, "bench", "traffic", "tiny-mix.json"),
               "w") as f:
-        json.dump(TINY_MIX, f)
+        json.dump(mix or TINY_MIX, f)
     spec["configs"].append({"name": "tiny", "source": "test",
                             "file": "bench/configs/tiny.json",
                             "reduced": [], "why": "test"})
     spec["workloads"].append({"name": "tiny.tiny-mix", "config": "tiny",
-                              "traffic": "tiny-mix", "chips": 1,
+                              "traffic": "tiny-mix", "chips": chips,
                               "why": "test"})
     path = os.path.join(tmp, "BENCHMARK.json")
     with open(path, "w") as f:

@@ -13,7 +13,7 @@ import pytest
 import benchpaths
 from benchpaths import BENCH, ROOT
 
-from harness import cells, peaks, runner, traffic
+from harness import cells, data, peaks, runner, traffic
 
 
 def _spec():
@@ -58,11 +58,17 @@ def test_new_cell_from_added_files_runs_on_cpu(tmp_path):
     assert all(c["value"] <= c["limit"] for c in out["checks"].values())
 
 
+def _table(col, pool_size):
+    return data.Dataset(vectors=np.zeros((len(col), 2), np.float32),
+                        numeric={"id": col},
+                        pool=np.zeros((pool_size, 2), np.float32))
+
+
 def test_schedule_gives_every_seed_the_same_work():
     mix = benchpaths.TINY_MIX
     col = np.random.default_rng(0).permutation(1000).astype(np.float32)
-    a = traffic.schedule(mix, {"id": col}, 300, 1, 5.0)
-    b = traffic.schedule(mix, {"id": col}, 300, 2 ** 31 + 5, 5.0)
+    a = traffic.schedule(mix, _table(col, 300), 1, 5.0)
+    b = traffic.schedule(mix, _table(col, 300), 2 ** 31 + 5, 5.0)
     assert len(a) == len(b) == traffic.count(mix, 5.0) == 100
     assert [x.t for x in a] != [x.t for x in b]
     assert sorted(x.archetype for x in a) == sorted(x.archetype for x in b)
@@ -72,18 +78,18 @@ def test_schedule_gives_every_seed_the_same_work():
     assert len(set(ga.tolist()) & set(gb.tolist())) >= len(ga) - 1
     assert 0.0 == a[0].t and max(x.t for x in a) < 5.0
     assert len({x.pool_idx for x in a}) == len(a)
-    lo = [x.filter[1] for x in a if x.filter]
+    lo = [x.filter[1]["lo"] for x in a if x.filter]
     assert lo and (col >= lo[0]).sum() == 100
 
 
 def test_schedule_windows_and_warmup_share_no_query():
     mix = benchpaths.TINY_MIX
     col = np.arange(1000, dtype=np.float32)
-    w = traffic.schedule(mix, {"id": col}, 300, 9, 5.0)
-    u = traffic.schedule(mix, {"id": col}, 300, 9, 2.0, skip=len(w))
+    w = traffic.schedule(mix, _table(col, 300), 9, 5.0)
+    u = traffic.schedule(mix, _table(col, 300), 9, 2.0, skip=len(w))
     assert not {x.pool_idx for x in w} & {x.pool_idx for x in u}
     with pytest.raises(ValueError):
-        traffic.schedule(mix, {"id": col}, 90, 9, 5.0)
+        traffic.schedule(mix, _table(col, 90), 9, 5.0)
 
 
 def test_peaks_refuse_an_unknown_device():

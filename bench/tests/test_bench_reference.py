@@ -7,7 +7,8 @@ import pytest
 
 import benchpaths  # noqa: F401
 
-from harness import data, reference, runner, traffic
+from harness import data, runner, traffic
+from harness.kinds import DEFAULT
 
 CFG = dict(benchpaths.TINY_CONFIG)
 
@@ -23,26 +24,22 @@ def _served(ds, specs):
     p = MQRLD(table, seed=3)
     p.prepare()
     srv = runner.server(runner.Setup(
-        cell=type("C", (), {"mix": benchpaths.TINY_MIX})(), seed=0, ds=ds,
-        platform=p, phase_s={}, compiles=None))
-    res = srv.serve([runner.request(s) for s in specs])
+        cell=type("C", (), {"mix": benchpaths.TINY_MIX, "chips": 1})(),
+        seed=0, ds=ds, platform=p, phase_s={}, compiles=None))
+    res = srv.serve([runner.request(s, ds) for s in specs])
     ids = p.view().row_ids
     return [np.asarray(ids)[r.rows] for r in res]
 
 
 @pytest.fixture(scope="module")
 def served():
-    ds = data.make(CFG)
-    specs = traffic.schedule(benchpaths.TINY_MIX, ds.numeric, len(ds.pool),
-                             5, 1.0)
+    ds = data.make(CFG, DEFAULT)
+    specs = traffic.schedule(benchpaths.TINY_MIX, ds, 5, 1.0)
     return ds, specs, _served(ds, specs)
 
 
 def _numbers(ds, specs, got):
-    ref = reference.Reference(ds.vectors, ds.numeric)
-    cands = ref.topk(ds.pool, specs, extra=runner.EXTRA)
-    return reference.compare(specs, got, cands, ds.vectors, ds.numeric,
-                             ds.pool)
+    return runner.check(ds, specs, got)
 
 
 def _passes(numbers):
@@ -62,9 +59,8 @@ def test_reference_agrees_with_served_path(served):
 def test_reference_agrees_on_other_tables(data_seed):
     # a configuration's table is fixed by its data_seed; the served path
     # must agree with the reference on tables other than the committed one
-    ds = data.make(dict(CFG, data_seed=data_seed))
-    specs = traffic.schedule(benchpaths.TINY_MIX, ds.numeric, len(ds.pool),
-                             data_seed + 1, 1.0)
+    ds = data.make(dict(CFG, data_seed=data_seed), DEFAULT)
+    specs = traffic.schedule(benchpaths.TINY_MIX, ds, data_seed + 1, 1.0)
     n = _numbers(ds, specs, _served(ds, specs))
     assert n["unanswered"] == 0 and n["wrong_rows"] == 0
     assert _passes(n), n
@@ -77,7 +73,7 @@ def test_a_wrong_answer_fails(served, fault):
     ds, specs, got = served
     got = [g.copy() for g in got]
     i = next(j for j, s in enumerate(specs) if s.filter is not None)
-    name, lo, hi = specs[i].filter
+    name, lo = specs[i].filter[1]["column"], specs[i].filter[1]["lo"]
     if fault == "far_row":
         q = ds.pool[specs[0].pool_idx]
         far = int(np.argmax(((ds.vectors - q) ** 2).sum(1)))
@@ -95,11 +91,11 @@ def test_a_wrong_answer_fails(served, fault):
 
 def test_bf16_control_fails():
     cfg = dict(CFG, rows=20000, dim=32, clusters=16, query_pool=400)
-    ds = data.make(cfg)
+    ds = data.make(cfg, DEFAULT)
     mix = dict(benchpaths.TINY_MIX, arrival={"process": "poisson",
                                              "rate_per_s": 100.0})
-    specs = traffic.schedule(mix, ds.numeric, len(ds.pool), 1, 2.0)
-    n = runner.control(ds, specs)
+    specs = traffic.schedule(mix, ds, 1, 2.0)
+    n = runner.control(ds, specs, runner.band())
     assert not _passes(n), n
 
 
