@@ -5,8 +5,9 @@ Each kind of thing a cell is made of that may grow is one file per name:
 - ``generators/<name>.py``: a configuration's table and pool
   (``make(cfg, column)``);
 - ``columns/<kind>.py``: a column kind (``make(spec, rows, rng)`` draws
-  the column, ``add(table, name, values)`` adds it to the program's
-  ``MMOTable``);
+  the column, a ``(rows, ...)`` array of any trailing shape;
+  ``add(table, name, values)`` adds it to the program's ``MMOTable``, as
+  one column or as several);
 - ``predicates/<kind>.py``: a filter node (``bind``, ``build``, ``mask``;
   see ``harness/predicates.py``);
 - ``metrics/<name>.py``: a per-layer metric's reader (``read(record)``).

@@ -10,7 +10,8 @@ of the first mixes, ``{"column", "pass_fraction"}``, which is the node
   only ``ctx.table`` (the configuration's ``Dataset``), ``ctx.vectors``
   (the request's held-out pool vectors, by the names the mix gives them,
   as pool indices) and ``ctx.cache`` (constants of this table, kept
-  across requests); ``ctx.bind(desc)`` binds a child.
+  across requests); ``ctx.bind(desc)`` binds a child. A held-out query's
+  own columns are ``ctx.table.pool_columns[name][ctx.vectors[v]]``.
 - ``build(params, ctx)``: the program's query node; ``ctx.pool`` is the
   held-out pool and ``ctx.build(node)`` builds a child. The only part
   that imports the program.
@@ -19,9 +20,12 @@ of the first mixes, ``{"column", "pass_fraction"}``, which is the node
   batched: each string is shared, each number an array of ``b``; a
   child's mask is ``sub(child)``. ``view`` is the table on the device
   (``jax.numpy``) or a few rows of it in float64 on the host
-  (``harness/reference.py``). ``band`` widens (> 0) or narrows (< 0) a
-  boundary that rounding decides by that share of its own size; a kind
-  whose test is exact ignores it. It imports nothing of the program.
+  (``harness/reference.py``); ``view.column(name)`` is ``(n, ...)`` as
+  the column is, and a kind reduces over its trailing axes. The
+  reference holds its padded rows out itself, whatever they hold.
+  ``band`` widens (> 0) or narrows (< 0) a boundary that rounding
+  decides by that share of its own size; a kind whose test is exact
+  ignores it. It imports nothing of the program.
 
 A bound node is ``(kind, params)``.
 """
@@ -45,6 +49,9 @@ def parse(desc: dict) -> Tuple[str, object]:
 
 
 class BindContext:
+    """What a kind's ``bind`` reads: ``table`` (its ``pool_columns`` too,
+    at ``vectors[name]``), ``vectors`` and ``cache``."""
+
     def __init__(self, table, vectors: Dict[str, int]):
         self.table = table
         self.vectors = vectors

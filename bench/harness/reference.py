@@ -91,7 +91,8 @@ class _DeviceView:
 
 
 class _HostView:
-    """A few rows of the table in float64, for a kind's ``mask``."""
+    """A few rows of the table in float64, for a kind's ``mask``; a
+    column's rows keep its trailing shape."""
 
     def __init__(self, ds, rows: np.ndarray):
         self.ds, self.rows = ds, rows
@@ -176,9 +177,11 @@ class Reference:
         self.x = jnp.asarray(x)
         self.xx = jnp.sum(self.x * self.x, axis=1)
         self.valid = jnp.asarray(np.arange(n + pad) < n)
-        # padded rows are outside every filter by ``valid``
+        # padded rows are outside every filter by ``valid``, whatever a
+        # kind would make of their zeros
         self.cols = {name: jnp.asarray(np.concatenate(
-            [c, np.zeros(pad, c.dtype)])) for name, c in ds.numeric.items()}
+            [c, np.zeros((pad,) + c.shape[1:], c.dtype)]))
+            for name, c in ds.numeric.items()}
         self.pool = jnp.asarray(ds.pool)
 
     def _run(self, specs: Sequence[Spec], kk: int, precision: str,

@@ -3,14 +3,14 @@ added files, on the CPU at a tiny size: each kind's reference mask
 against the program's own brute-force oracle, a tiny hybrid window, a
 served row just outside the band, the bfloat16 control, a generator,
 column kind and predicate kind written only into a copy of the
-benchmark, and a four-chip cell served sharded."""
+benchmark (plain ones, and a tag bag with the queries' own tags and
+uint8 codes), and a four-chip cell served sharded."""
 import dataclasses
 import filecmp
 import json
 import os
 import subprocess
 import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -232,11 +232,9 @@ def test_kinds_added_as_files_run_a_cell(tmp_path):
          "filter": {"and": [eq, {"nr": {"column": "id",
                                         "pass_fraction": 0.5}}]}}])
     path = benchpaths.add_cell(str(tmp_path), cfg, mix)
-    for folder, name, text in (("generators", "unit_cube", GENERATOR),
-                               ("columns", "coin", COLUMN),
-                               ("predicates", "eq", PREDICATE)):
-        (tmp_path / "bench" / folder / f"{name}.py").write_text(
-            textwrap.dedent(text))
+    benchpaths.write_kinds(str(tmp_path / "bench"), {
+        ("generators", "unit_cube"): GENERATOR, ("columns", "coin"): COLUMN,
+        ("predicates", "eq"): PREDICATE})
     cmp = filecmp.dircmp(os.path.join(BENCH, "harness"),
                          str(tmp_path / "bench" / "harness"),
                          ignore=["__pycache__"])
@@ -249,6 +247,45 @@ def test_kinds_added_as_files_run_a_cell(tmp_path):
     ds = data.make(cell.config, cell.kinds)
     assert set(np.unique(ds.numeric["h"])) == {0.0, 1.0}
     assert ds.vectors.min() >= 0.0 and ds.vectors.max() < 1.0
+
+
+def test_set_valued_kinds_added_as_files_run_a_cell(tmp_path):
+    """A bag of tags a row, each query's own tags from the pool's own
+    column and uint8 codes, all from files added beside an unchanged
+    harness: a window over one tag, two tags and a tag fewer rows than
+    k carry is correct."""
+    import jax
+    mix = benchpaths.TAG_MIX
+    path = benchpaths.add_cell(str(tmp_path), benchpaths.TAG_CONFIG, mix)
+    benchpaths.write_kinds(str(tmp_path / "bench"), benchpaths.TAG_KINDS)
+    cmp = filecmp.dircmp(os.path.join(BENCH, "harness"),
+                         str(tmp_path / "bench" / "harness"),
+                         ignore=["__pycache__"])
+    assert not (cmp.diff_files or cmp.left_only or cmp.right_only)
+    cell = cells.resolve(path, "tiny.tiny-mix")
+    seed = 2 ** 31 + 17
+    out = runner.run_cell(cell, seed, 1.0, trace=False, t_start=0.0,
+                          device=jax.devices()[0], log=lambda m: None)
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] == 20 and out["failed"] == 0
+
+    # the generator's uint8 codes (test_bench_reference.py), as float32
+    ds = data.make(cell.config, cell.kinds)
+    assert ds.vectors.dtype == np.float32 and ds.pool.dtype == np.float32
+    assert (ds.vectors == np.rint(ds.vectors)).all()
+    assert ds.vectors.min() >= 0 and ds.vectors.max() <= 255
+    tags = ds.numeric["tags"]
+    assert tags.shape == (1500, 3) and tags.min() == -1
+    held = (tags >= 0).sum(axis=1)
+    assert 0.2 < (held < 3).mean() < 0.9 and held.min() >= 1
+    assert ds.pool_columns["qtags"].shape == (300, 2)
+    specs = traffic.schedule(mix, ds, seed, 1.0)
+    assert {s.archetype for s in specs} == {a["name"] for a in
+                                            mix["archetypes"]}
+    for s in specs:
+        if s.archetype == "vk100_rare_tag":
+            n = (tags == s.filter[1]["tag"]).any(axis=1).sum()
+            assert 0 < n < s.k
 
 
 def test_one_chip_cell_passes_no_shards(monkeypatch):

@@ -1,14 +1,16 @@
 """The comparison that decides ``correct``: the plain reference agrees
 with the served path at a tiny size, a wrong row fails it, the bfloat16
 control fails it, and a run with the timed path broken underneath comes
-out not correct."""
+out not correct. On a set-valued tag column: the reference agrees with a
+numpy brute force past its padding, a uint8 table with its float32 copy,
+and a served row that lacks the tag is a wrong row."""
 import numpy as np
 import pytest
 
-import benchpaths  # noqa: F401
+import benchpaths
 
-from harness import data, runner, traffic
-from harness.kinds import DEFAULT
+from harness import data, predicates, reference, runner, traffic
+from harness.kinds import DEFAULT, Kinds
 
 CFG = dict(benchpaths.TINY_CONFIG)
 
@@ -131,3 +133,113 @@ def test_run_with_broken_timed_path_is_not_correct(tmp_path, monkeypatch,
     assert out["checks"]["wrong_rows"]["value"] > 0 or \
         out["checks"]["kth_excess"]["value"] > \
         out["checks"]["kth_excess"]["limit"]
+
+
+@pytest.fixture(scope="module")
+def tag_kinds(tmp_path_factory):
+    bench = tmp_path_factory.mktemp("tagkinds")
+    benchpaths.write_kinds(str(bench), benchpaths.TAG_KINDS)
+    return Kinds(str(bench))
+
+
+def _tag_specs(ds, k, slot=0):
+    """One tag request per held-out query: its own tag in ``slot``."""
+    return [traffic.Spec(t=0.0, pool_idx=i, k=k, archetype="tag",
+                         filter=predicates.bind(benchpaths.tag(slot), ds,
+                                                {"q": i}))
+            for i in range(len(ds.pool))]
+
+
+def _brute(ds, spec):
+    """Float64 distances of the rows carrying the request's tag, and
+    those rows, nearest first."""
+    tags = ds.numeric["tags"]
+    rows = np.nonzero((tags == spec.filter[1]["tag"]).any(axis=1))[0]
+    d = reference._d2(ds.vectors, rows, ds.pool[spec.pool_idx])
+    order = np.argsort(d, kind="stable")
+    return d[order], rows[order]
+
+
+@pytest.mark.parametrize("rows", [1000, reference._CHUNK + 1000])
+def test_tag_column_reference_agrees_with_numpy(tag_kinds, rows):
+    """Tag 0 is in use, by fewer rows than k: the reference's padded
+    rows, zero vectors holding tag 0 in every slot, would enter its
+    answers unless ``valid`` held them out."""
+    rng = np.random.default_rng(rows)
+    dim, k = 8, 10
+    tags = rng.integers(1, 12, (rows, 3)).astype(np.int32)
+    tags[rng.random((rows, 3)) < 0.4] = -1
+    tags[:, 0] = np.where(tags[:, 0] < 0, 1, tags[:, 0])
+    tags[rng.choice(rows, 3, replace=False), 2] = 0
+    qtags = np.stack([np.arange(40) % 4, np.full(40, 1)], axis=1)
+    ds = data.Dataset(
+        vectors=rng.standard_normal((rows, dim), dtype=np.float32),
+        numeric={"tags": tags},
+        pool=rng.standard_normal((40, dim), dtype=np.float32) * 0.1,
+        pool_columns={"qtags": qtags}, kinds=tag_kinds)
+    specs = _tag_specs(ds, k)
+    ref = reference.Reference(ds)
+    got = ref.topk(specs, extra=0)
+    cands = ref.candidates(specs, extra=runner.EXTRA, band=runner.band())
+    for s, g, c in zip(specs, got, cands):
+        d, want = _brute(ds, s)
+        assert c.n_loose == c.n_strict == len(want)
+        assert np.array_equal(g, want[:k]), (s.filter, g, want[:k])
+    assert any(len(g) < k for g in got)
+    n = reference.compare(specs, got, cands, ds, runner.band())
+    assert n == {"unanswered": 0, "wrong_rows": 0, "kth_excess": 0.0,
+                 "order_excess": 0.0}
+
+
+@pytest.fixture(scope="module")
+def uint8_table(tag_kinds):
+    cfg = dict(benchpaths.TAG_CONFIG, rows=3000, dim=192, query_pool=60)
+
+    def column(spec, rows, rng):
+        return tag_kinds.module("columns", spec["kind"]).make(spec, rows, rng)
+
+    raw = tag_kinds.module("generators", "tag_codes").make(cfg, column)
+    return raw, data.make(cfg, tag_kinds)
+
+
+def test_uint8_table_agrees_with_its_float32_copy(uint8_table):
+    """The source's uint8 codes, held as float32 by ``data.make``, give
+    the reference the answers of the same values made float32 by hand,
+    and the nearest rows by a float64 brute force."""
+    raw, ds = uint8_table
+    assert raw.vectors.dtype == np.uint8 and raw.pool.dtype == np.uint8
+    assert ds.vectors.dtype == np.float32 and ds.pool.dtype == np.float32
+    copy = data.Dataset(vectors=raw.vectors.astype(np.float32),
+                        numeric=raw.numeric,
+                        pool=raw.pool.astype(np.float32),
+                        pool_columns=raw.pool_columns, kinds=ds.kinds)
+    specs = _tag_specs(ds, 10)
+    specs += [traffic.Spec(t=0.0, pool_idx=i, k=10, archetype="vk",
+                           filter=None) for i in range(len(ds.pool))]
+    got = reference.Reference(ds).topk(specs, extra=0)
+    want = reference.Reference(copy).topk(specs, extra=0)
+    assert all(np.array_equal(g, w) for g, w in zip(got, want))
+    every = np.arange(len(ds.vectors))
+    for s, g in zip(specs, got):
+        q = ds.pool[s.pool_idx]
+        d = reference._d2(ds.vectors, g, q)
+        full = np.sort(reference._d2(ds.vectors, every, q)) \
+            if s.filter is None else _brute(ds, s)[0]
+        assert np.array_equal(np.sort(d), full[:s.k])
+    n = runner.check(ds, specs, want)
+    assert n["wrong_rows"] == 0 and n["kth_excess"] == 0.0, n
+
+
+def test_served_row_lacking_the_tag_is_a_wrong_row(uint8_table):
+    _, ds = uint8_table
+    specs = _tag_specs(ds, 10)
+    served = reference.Reference(ds).topk(specs, extra=0)
+    assert runner.check(ds, specs, served)["wrong_rows"] == 0
+    i = next(j for j, g in enumerate(served) if len(g) == specs[j].k)
+    tag = specs[i].filter[1]["tag"]
+    lacking = np.nonzero(~(ds.numeric["tags"] == tag).any(axis=1))[0]
+    d = reference._d2(ds.vectors, lacking, ds.pool[specs[i].pool_idx])
+    bad = [g.copy() for g in served]
+    bad[i][-1] = lacking[np.argmin(d)]       # the nearest row without it
+    n = runner.check(ds, specs, bad)
+    assert n["wrong_rows"] >= 1, n
